@@ -5,6 +5,9 @@ stochastically-quantized extrapolation variables (paper Alg 4 on ICI).
   PYTHONPATH=src python examples/gossip_ecd_psgd.py
 """
 import os
+# eight forced host CPU devices; JAX_PLATFORMS=cpu keeps this process off
+# any accelerator (a chip belongs to one process at a time)
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys; sys.path.insert(0, "src")
 import jax, jax.numpy as jnp

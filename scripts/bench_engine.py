@@ -369,7 +369,10 @@ def time_distributed(args, device_counts=(1, 8), repeats=2):
     contaminated estimate of what the configuration can do."""
     out = {}
     for ndev in device_counts:
+        # JAX_PLATFORMS=cpu: the forced host devices are the point, and
+        # a child must never contend for an accelerator this process holds
         env = {**os.environ,
+               "JAX_PLATFORMS": "cpu",
                "XLA_FLAGS": f"--xla_force_host_platform_device_count={ndev}",
                "PYTHONPATH": "src" + (
                    os.pathsep + os.environ["PYTHONPATH"]

@@ -30,6 +30,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import runtime
+
 
 def feature_mean(X):
     return jnp.mean(X, axis=0)
@@ -104,7 +106,7 @@ def _csim_scan(X, rng: int, tol):
 def _default_use_kernel() -> bool:
     # interpret-mode Pallas off-TPU is emulation: correct but slower than
     # the fused jnp scan, so the kernels are the default on TPU only
-    return jax.default_backend() == "tpu"
+    return runtime.default_platform() == "tpu"
 
 
 def csim(X, rng: int, tol=0.0, use_kernel=None):

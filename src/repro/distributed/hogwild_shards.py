@@ -8,7 +8,7 @@ oracle**: deterministic, single-device, and what every grid sweep and
 cache artifact is defined by.
 
 This module runs the race for real.  The ``m`` workers are split into
-``D`` shards (one per mesh device) under ``jax.experimental.shard_map``;
+``D`` shards (one per mesh device) under ``jax.shard_map``;
 each shard races ahead on its own copy of the parameter vector — its
 local workers apply full-step SGD updates sequentially, *reading*
 whatever their shard's copy currently holds — and every ``sync_every``
@@ -37,7 +37,6 @@ from typing import Dict, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.algorithms.lr import LAMBDA, lr_grad, test_logloss
@@ -188,18 +187,18 @@ def _build_race(X, y, Xte, yte, dmesh, *, w, gamma, lam, sync_every,
         return x, losses
 
     if fspec is None:
-        mapped = shard_map(
+        mapped = jax.shard_map(
             shard_fn, mesh=dmesh.mesh,
             in_specs=(P(), P(None, None, mesh_mod.SHARD_AXIS, None),
                       P(mesh_mod.SHARD_AXIS, None)),
-            out_specs=(P(), P()), check_rep=False)
+            out_specs=(P(), P()), check_vma=False)
     else:
-        mapped = shard_map(
+        mapped = jax.shard_map(
             shard_fn_faulted, mesh=dmesh.mesh,
             in_specs=(P(), P(None, None, mesh_mod.SHARD_AXIS, None),
                       P(mesh_mod.SHARD_AXIS, None),
                       P(None, None, mesh_mod.SHARD_AXIS, None)),
-            out_specs=(P(), P()), check_rep=False)
+            out_specs=(P(), P()), check_vma=False)
     _JIT_CALLS.inc()
     return jax.jit(mapped, donate_argnums=(0,))
 

@@ -29,7 +29,7 @@ import warnings
 from typing import Optional, Sequence, Union
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 #: the sweep mesh's single axis name (the batched grid-element axis)
 SHARD_AXIS = "shard"
@@ -139,15 +139,21 @@ def resolve(mesh: MeshLike) -> Optional[DeviceMesh]:
 # Model-stack meshes (absorbed from the former repro.launch.mesh)
 # ---------------------------------------------------------------------------
 
+def _auto_mesh(shape, axes):
+    # Auto axes: the model stack places arrays with sharding constraints,
+    # which `jax.make_mesh`'s default Explicit axes refuse
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips when multi_pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_debug_mesh(data=2, model=2, pod=0):
     """Small mesh for tests (requires xla_force_host_platform_device_count)."""
     if pod:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return _auto_mesh((pod, data, model), ("pod", "data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))

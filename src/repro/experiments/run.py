@@ -24,7 +24,8 @@ mesh size, so a sweep computed on 1 device is a cache hit on 8.
 Repeated runs of an unchanged spec are served from the artifact cache
 (--force recomputes, --no-cache bypasses it).  --json writes the full
 result payload; the stdout report ends with the measured-vs-predicted
-m_max comparison whenever the spec produces both sides.
+m_max comparison whenever the spec produces both sides.  The exit status
+is 1 when any job ends ``failed`` (every attempt raised).
 
 ``--trace out.json`` records the run as nested spans (sweep -> job ->
 bucket -> lower/compile/execute, journal and cache IO) and writes
@@ -47,6 +48,7 @@ import dataclasses
 import json
 import sys
 
+from repro import runtime
 from repro.core import problems as problems_mod
 from repro.core.algorithms import base as alg_base
 from repro.data import synth
@@ -82,6 +84,9 @@ def _print_report(result: dict) -> None:
     print()
     comparisons = []
     for key, jr in result["jobs"].items():
+        if jr.get("status") == "failed":
+            print(f"{key:28s} FAILED: {jr['error']}")
+            continue
         curves = runner.curves_by_m(jr)
         finals = "  ".join(f"m{m}={c[-1]:.4f}" for m, c in curves.items())
         print(f"{key:28s} final loss: {finals}")
@@ -194,6 +199,7 @@ def main(argv=None) -> int:
     if args.list:
         _print_registries()
         return 0
+    runtime.enable_compile_cache()
     if not args.spec:
         ap.error("--spec is required (or --list)")
 
@@ -257,6 +263,11 @@ def main(argv=None) -> int:
         print()
         print(metrics_mod.REGISTRY.render_prometheus(prefix="repro_"),
               end="")
+    failed = [k for k, jr in result["jobs"].items()
+              if jr.get("status") == "failed"]
+    if failed:
+        print(f"FAILED jobs: {', '.join(failed)}", file=sys.stderr)
+        return 1
     return 0
 
 

@@ -59,6 +59,7 @@ from typing import Dict, List, Optional
 import jax
 import numpy as np
 
+from repro import runtime
 from repro.analysis import fit as fit_mod
 from repro.core import metrics as MX
 from repro.core import scalability as SC
@@ -270,7 +271,7 @@ def run_sweep(spec: SweepSpec, *, use_cache: bool = True, force: bool = False,
             # a host that cannot satisfy the spec's `devices` ask
             hit["execution"] = {"devices": len(jax.devices()),
                                 "sharded": False,
-                                "backend": jax.default_backend()}
+                                "backend": runtime.default_platform()}
             return hit
         if not dedup or leased:
             break
@@ -310,9 +311,16 @@ def _compute_sweep(spec: SweepSpec, fp: str, cache_dir: str, *,
     `run_sweep` wraps exactly one compute in try/finally.  The whole
     compute runs under a root ``sweep`` span — its children (datasets,
     per-job grids, journal/cache IO) are the phase breakdown the report
-    and ``--trace`` surface."""
+    and ``--trace`` surface.
+
+    Sweeps are specified in float32, so the compute runs at matmul
+    precision ``"highest"``: a TPU otherwise runs f32 matmuls as one bf16
+    pass, which moved curves by ~1e-3 on a v5e and broke the
+    mesh-invariance contract (docs/distributed.md).  The CPU computes f32
+    matmuls either way."""
     with trace.span("sweep", spec=spec.name, fingerprint=fp[:12],
-                    jobs=len(spec.jobs)):
+                    jobs=len(spec.jobs)), \
+            jax.default_matmul_precision("highest"):
         return _compute_sweep_inner(
             spec, fp, cache_dir, use_cache=use_cache, force=force,
             use_vmap=use_vmap, verbose=verbose, mesh=mesh, journal=journal,
@@ -346,7 +354,7 @@ def _compute_sweep_inner(spec: SweepSpec, fp: str, cache_dir: str, *,
     execution = {
         "devices": dmesh.n_devices if dmesh is not None else 1,
         "sharded": dmesh is not None and dmesh.n_devices > 1 and use_vmap,
-        "backend": jax.default_backend(),
+        "backend": runtime.default_platform(),
     }
 
     # perf_counter is monotonic — wall-clock (time.time) steps under NTP

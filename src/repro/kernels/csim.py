@@ -34,7 +34,7 @@ def _l0_kernel(x_ref, y_ref, o_ref, *, tol, nd):
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "bd", "interpret", "tol"))
-def l0_rows(x, y, *, tol=0.0, bn=DEFAULT_BN, bd=DEFAULT_BD, interpret=True):
+def l0_rows(x, y, *, tol=0.0, bn=DEFAULT_BN, bd=DEFAULT_BD, interpret=False):
     """Per-row L0 distance between x and y: (n, d) x (n, d) -> (n,)."""
     n, d = x.shape
     bn = min(bn, n)
@@ -61,7 +61,7 @@ def l0_rows(x, y, *, tol=0.0, bn=DEFAULT_BN, bd=DEFAULT_BD, interpret=True):
 
 
 @functools.partial(jax.jit, static_argnames=("rng", "tol", "interpret"))
-def csim_kernel(X, rng: int, tol=0.0, *, interpret=True):
+def csim_kernel(X, rng: int, tol=0.0, *, interpret=False):
     """Eq. 3 via the Pallas L0 kernel, fused as one `lax.scan` over the
     shift range — one trace and one compiled pipeline regardless of rng
     (the old wrapper unrolled rng separate pallas calls)."""

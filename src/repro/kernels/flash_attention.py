@@ -74,7 +74,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 @functools.partial(jax.jit, static_argnames=("causal", "window", "bq", "bk",
                                              "interpret"))
 def flash_attention_bhsd(q, k, v, *, causal=True, window=0,
-                         bq=DEFAULT_BQ, bk=DEFAULT_BK, interpret=True):
+                         bq=DEFAULT_BQ, bk=DEFAULT_BK, interpret=False):
     """q: (B, H, S, D); k, v: (B, KV, T, D) -> (B, H, S, D).
 
     S % bq == 0 and T % bk == 0 (the ops.py wrapper pads).

@@ -1,15 +1,17 @@
 """jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only; the
-kernels are written for TPU BlockSpec tiling and validated in interpret
-mode per the project contract).
+The kernel modules default to compiled Pallas.  These wrappers pick
+interpret mode when the computation will not run on a TPU (the tests and
+CPU runs) and the compiled kernel when it will; the choice follows
+`repro.runtime.default_platform`, so ``with jax.default_device(cpu)``
+on a TPU host interprets too.
 """
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro import runtime
 from repro.kernels import csim as _csim
 from repro.kernels import flash_attention as _fa
 from repro.kernels import quantize as _q
@@ -17,7 +19,7 @@ from repro.kernels import rmsnorm as _rn
 
 
 def _interpret_default():
-    return jax.default_backend() != "tpu"
+    return runtime.default_platform() != "tpu"
 
 
 def flash_attention(q, k, v, *, causal=True, window=0, bq=None, bk=None):

@@ -37,7 +37,7 @@ def _dequant_kernel(q_ref, scale_ref, x_ref):
 
 @functools.partial(jax.jit, static_argnames=("bits", "bn", "bd", "interpret"))
 def quantize_stochastic_2d(x, key, *, bits=8, bn=DEFAULT_BN, bd=DEFAULT_BD,
-                           interpret=True):
+                           interpret=False):
     """x: (n, d) -> (q int8/int16, scale)."""
     assert bits in (4, 8, 16)
     qmax = 2.0 ** (bits - 1) - 1.0
@@ -66,7 +66,7 @@ def quantize_stochastic_2d(x, key, *, bits=8, bn=DEFAULT_BN, bd=DEFAULT_BD,
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "bd", "interpret"))
-def dequantize_2d(q, scale, *, bn=DEFAULT_BN, bd=DEFAULT_BD, interpret=True):
+def dequantize_2d(q, scale, *, bn=DEFAULT_BN, bd=DEFAULT_BD, interpret=False):
     n, d = q.shape
     bn = min(bn, n)
     bd = min(bd, d)

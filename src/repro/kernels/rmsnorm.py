@@ -25,7 +25,7 @@ def _rmsnorm_kernel(x_ref, g_ref, o_ref, *, eps):
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "br", "interpret"))
-def rmsnorm_2d(x, gain, *, eps=1e-6, br=DEFAULT_BR, interpret=True):
+def rmsnorm_2d(x, gain, *, eps=1e-6, br=DEFAULT_BR, interpret=False):
     """x: (n, d), gain: (d,) -> (n, d)."""
     n, d = x.shape
     br = min(br, n)

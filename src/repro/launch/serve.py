@@ -13,6 +13,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro import runtime
 from repro.configs.registry import ARCH_IDS, get_arch
 from repro.models import model as M
 from repro.serve.engine import greedy_generate
@@ -26,6 +27,7 @@ def main():
     ap.add_argument("--gen", type=int, default=24)
     ap.add_argument("--reduced", action="store_true", default=True)
     args = ap.parse_args()
+    runtime.enable_compile_cache()
 
     cfg = get_arch(args.arch).reduced()
     key = jax.random.PRNGKey(0)

@@ -5,19 +5,22 @@ CPU-scale real runs (reduced configs, actual optimization):
       --steps 50 --strategy sync
 
 Production lowering (full config, mesh, no execution) is dryrun.py's job —
-this launcher EXECUTES.  On the CPU container it therefore defaults to the
-reduced configs; passing --full without a TPU will be slow/OOM and warns.
+this launcher EXECUTES.  It defaults to the reduced configs; --full runs
+the published widths, which needs a TPU (gemma3-1b at batch 8 x 64 fits
+one v5e chip) and warns elsewhere.
 """
 
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import time
 
 import jax
 import jax.numpy as jnp
 
+from repro import runtime
 from repro.configs.registry import ARCH_IDS, get_arch
 from repro.core.advisor import ScalabilityAdvisor
 from repro.data.lm import LMConfig, hmm_stream, token_characters
@@ -29,7 +32,7 @@ from repro.train.checkpoint import save_checkpoint
 def train_loop(cfg, *, steps=50, batch_size=8, seq_len=64, lr=1e-3,
                strategy="sync", log_every=10, ckpt=None, advisor_every=0,
                lb_coef=0.01, key=None):
-    key = key or jax.random.PRNGKey(0)
+    key = key if key is not None else jax.random.PRNGKey(0)
     params = M.init_params(key, cfg)
     opt = adamw_init(params)
     prev_grads = (jax.tree.map(jnp.zeros_like, params)
@@ -38,13 +41,15 @@ def train_loop(cfg, *, steps=50, batch_size=8, seq_len=64, lr=1e-3,
     def loss_fn(p, batch):
         return M.loss_fn(p, cfg, batch, lb_coef=lb_coef)
 
-    @jax.jit
+    # params and optimizer state are donated: updated in place, the
+    # full-width step fits one chip (no second copy of either)
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
     def sync_step(p, opt, batch):
         (l, aux), g = jax.value_and_grad(loss_fn, has_aux=True)(p, batch)
         p, opt = adamw_update(p, g, opt, lr=lr)
-        return p, opt, l, g
+        return p, opt, l
 
-    @jax.jit
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
     def stale_step(p, opt, prev_g, batch):
         (l, aux), g = jax.value_and_grad(loss_fn, has_aux=True)(p, batch)
         p, opt = adamw_update(p, prev_g, opt, lr=lr)
@@ -67,7 +72,7 @@ def train_loop(cfg, *, steps=50, batch_size=8, seq_len=64, lr=1e-3,
             params, opt, l, g = stale_step(params, opt, prev_grads, batch)
             prev_grads = g
         else:
-            params, opt, l, g = sync_step(params, opt, batch)
+            params, opt, l = sync_step(params, opt, batch)
         history.append(float(l))
         if step % log_every == 0:
             msg = f"step {step:4d} loss {float(l):.4f}"
@@ -109,6 +114,7 @@ def main():
     ap.add_argument("--advisor-every", type=int, default=0)
     ap.add_argument("--json")
     args = ap.parse_args()
+    runtime.enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

@@ -34,6 +34,7 @@ import json
 import sys
 import time
 
+from repro import runtime
 from repro.experiments.spec import DatasetSpec
 from repro.service.api import AdvisorService, ProbeRequest
 from repro.service.http import ServiceServer
@@ -90,6 +91,7 @@ def main(argv=None) -> int:
     p.add_argument("--host", default="127.0.0.1",
                    help="--serve bind address (default 127.0.0.1)")
     args = p.parse_args(argv)
+    runtime.enable_compile_cache()
 
     kw = {}
     if args.threshold is not None:

@@ -187,7 +187,6 @@ def make_gossip_step(cfg: ArchConfig, mesh, *, lr=3e-4, compress_bits=8,
         step(state, batch) -> (state, metrics)
     """
     from jax.sharding import PartitionSpec
-    from jax.experimental.shard_map import shard_map
 
     from repro.core.compression import dequantize, quantize_stochastic
 
@@ -268,10 +267,10 @@ def make_gossip_step(cfg: ArchConfig, mesh, *, lr=3e-4, compress_bits=8,
         b_specs = jax.tree.map(
             lambda x: PartitionSpec(fd, *([None] * (x.ndim - 1))),
             batch_shapes)
-        step = shard_map(local_step, mesh=mesh,
-                         in_specs=(st_specs, b_specs),
-                         out_specs=(st_specs, {"loss": PartitionSpec()}),
-                         check_rep=False)
+        step = jax.shard_map(local_step, mesh=mesh,
+                             in_specs=(st_specs, b_specs),
+                             out_specs=(st_specs, {"loss": PartitionSpec()}),
+                             check_vma=False)
         return step, st_specs, b_specs
 
     return make, R

@@ -29,8 +29,11 @@ RNG = np.random.default_rng(11)
 
 
 @pytest.fixture(autouse=True)
-def _clean_slate():
+def _clean_slate(monkeypatch):
     trace.stop()
+    # /trace falls back to the last tracer; one left by an earlier test
+    # file in this worker process must not leak into these payloads
+    monkeypatch.setattr(trace, "_LAST", None)
     RECORDER.clear()
     yield
     trace.stop()

@@ -356,6 +356,22 @@ def test_permanent_failure_becomes_structured_stub(tmp_path, monkeypatch):
     assert runner.run_sweep(spec, cache_dir=str(tmp_path))["cache"]["hit"]
 
 
+def test_cli_exits_nonzero_on_failed_job(monkeypatch, capsys):
+    """A job whose every attempt raised (a chip compile error, say) makes
+    the sweep CLI exit 1 instead of reporting success over a stub."""
+    from repro.experiments import run as cli
+
+    def broken(*args, **kwargs):
+        raise RuntimeError("device pool gone")
+
+    monkeypatch.setattr(engine, "run_algorithm_sweep", broken)
+    with pytest.warns(RuntimeWarning, match="failed after"):
+        rc = cli.main(["--spec", "variance_sparsity", "--quick",
+                       "--iters", "40", "--n", "120", "--no-cache"])
+    assert rc == 1
+    assert "FAILED jobs:" in capsys.readouterr().err
+
+
 def test_diverged_job_excluded_from_readouts(tmp_path):
     """A diverged cell keeps its curves and a 'diverged' status but stays
     out of the epsilon/cost readout, the predictor, and the characters
