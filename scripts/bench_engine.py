@@ -25,16 +25,16 @@ process metrics registry, instrumented through the engine, runner,
 distributed, and service layers.  The contract is *zero overhead when
 disabled*: with no tracer installed every `trace.span(...)` returns one
 shared no-op object, and the engine dispatches the jitted grid exactly
-as before (the AOT lower/compile/execute split only happens under an
-active tracer).  The **telemetry** section measures that contract: the
+as before (a tracer adds spans from JAX's compile events, on the same
+jit calls).  The **telemetry** section measures that contract: the
 full engine_default sweep through `run_sweep` with tracing off vs on
 (warm jit caches, fresh cache dir per run, off/on *interleaved* and
 min-reduced over repeats so a slow system phase hits both labels), plus
 the per-span record cost isolated.  The claims: disabled overhead < 1%
 (the acceptance gate — the off path must stay within noise of the
 **vs_bench8** anchor below), and the enabled tax is bounded and
-reported honestly (the traced path re-lowers each bucket once to split
-compile from execute, so it pays roughly one extra trace per bucket).
+reported honestly (the traced path records a span per JAX compile
+event and enters a profiler annotation per span).
 The **vs_bench8** block embeds BENCH_8's engine_default wall-clock for
 the non-regression comparison: telemetry is observational only —
 artifact bytes are identical on/off (tests/test_telemetry.py), so the

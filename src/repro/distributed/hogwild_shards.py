@@ -42,7 +42,7 @@ from jax.sharding import PartitionSpec as P
 from repro.core.algorithms.lr import LAMBDA, lr_grad, test_logloss
 from repro.distributed import mesh as mesh_mod
 from repro.resilience import faults
-from repro.telemetry import instrument, metrics, recorder
+from repro.telemetry import instrument, metrics, recorder, trace
 
 #: compile counter for the sharded racing mode — `scripts/bench_engine.py
 #: dist_worker` snapshots it around the race timing (the engine's own
@@ -262,6 +262,8 @@ def run_hogwild_sharded(train, test, *, m: int = 8, iters: int = 4000,
         x, losses = instrument.dispatch(
             race, x0, samples, mask, fstream, span_name="race",
             m=m, devices=D, sync_every=sync_every, faulted=True)
+    with trace.span("fetch"):
+        losses = jax.device_get(losses)
     # host-side mirror of the pipeline's sync schedule: the global round
     # counter r hits (r % sync_every == sync_every - 1) exactly
     # R_total // sync_every times over R_total rounds, and every eval
@@ -279,7 +281,7 @@ def run_hogwild_sharded(train, test, *, m: int = 8, iters: int = 4000,
         "sync_every": sync_every,
         "iters": n_evals * eval_every,
         "eval_every": eval_every,
-        "losses": jax.device_get(losses),
+        "losses": losses,
         "x": x,
         "iters_per_worker": iters / m,
         "psum_rounds": psum_rounds,

@@ -67,15 +67,19 @@ def element_plan(pos: Sequence[int], ms: Sequence[int], n_seeds: int,
 def run_grid_sharded(make_sim_elem: Callable, ms: Sequence[int],
                      n_seeds: int, dmesh: DeviceMesh,
                      buckets: List[Tuple[Tuple[int, ...], int]],
-                     jit_fn: Callable = jax.jit) -> jnp.ndarray:
+                     jit_fn: Callable = jax.jit,
+                     algorithm: str = "sim") -> jnp.ndarray:
     """Run the whole grid sharded over ``dmesh``; rows follow ``ms`` order.
 
-    ``make_sim_elem(m_pad)`` must return ``sim_elem(m, s) -> (n_evals,)``
-    obeying the engine's masked-simulation contract (numerics independent
-    of ``m_pad`` for any ``m <= m_pad``); ``buckets`` is the engine's
+    ``make_sim_elem(m_pad)`` must return ``(sim_elem, consts)``:
+    ``sim_elem(m, s) -> (n_evals,)`` obeying the engine's masked-simulation
+    contract (numerics independent of ``m_pad`` for any ``m <= m_pad``),
+    and the arrays it closes over (their bytes are the ``mesh_bucket``
+    span's ``const_bytes``); ``buckets`` is the engine's
     ``[(positions, m_pad), ...]`` partition (a single flat bucket for
     ``force_flat`` algorithms).  ``jit_fn`` is injected so the engine's
-    ``JIT_CALLS`` compile accounting covers the sharded path too.
+    ``JIT_CALLS`` compile accounting covers the sharded path too; each
+    bucket's program is named ``bucket_<algorithm>_m<m_pad>``.
 
     Returns ``(S, n_evals)`` for ``n_seeds == 1``, else
     ``(S, n_seeds, n_evals)`` — the same contract as the engine's
@@ -90,10 +94,13 @@ def run_grid_sharded(make_sim_elem: Callable, ms: Sequence[int],
                         elements=len(m_idx)):
             m_arr = jax.device_put(m_idx, sharded)
             s_arr = jax.device_put(s_idx, sharded)
+        sim_elem, consts = make_sim_elem(m_pad)
+        program = instrument.named(jax.vmap(sim_elem),
+                                   f"bucket_{algorithm}_m{m_pad}")
         out = instrument.dispatch(
-            jit_fn(jax.vmap(make_sim_elem(m_pad))), m_arr, s_arr,
-            span_name="mesh_bucket", devices=dmesh.n_devices,
-            elements=len(m_idx), m_pad=m_pad)
+            jit_fn(program), m_arr, s_arr, span_name="mesh_bucket",
+            algorithm=algorithm, m_pad=m_pad, devices=dmesh.n_devices,
+            elements=len(m_idx), const_bytes=instrument.nbytes(consts))
         with trace.span("gather", elements=n_real):
             out = np.asarray(jax.device_get(out))[:n_real]
         out = out.reshape(len(pos), n_seeds, -1)

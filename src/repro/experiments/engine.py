@@ -137,7 +137,8 @@ def _losses_dict(algorithm: str, ms, losses, iters: int, eval_every: int,
     ``problem`` key is new in ENGINE_VERSION 3, ``n_seeds``/``losses_seeds``
     in ENGINE_VERSION 4 (both additive — legacy keys are unchanged;
     ``losses`` is always the seed-0 rows)."""
-    losses = jax.device_get(losses)
+    with trace.span("fetch"):
+        losses = jax.device_get(losses)
     out = {
         "algorithm": algorithm,
         "problem": problem,
@@ -182,35 +183,45 @@ def _buckets(ms: Sequence[int],
     return out
 
 
-def _run_grid(make_sim, ms, use_vmap: bool, bucketed: bool = True):
-    """Run ``sim = make_sim(m_pad)`` over the grid; rows follow ``ms`` order.
+def _bucket_program(sim, algorithm: str, m_pad: int):
+    """The vmapped simulation, named ``bucket_<algorithm>_m<m_pad>`` (the
+    trace's ``jit_bucket_...`` module) and jitted."""
+    return _jit(instrument.named(jax.vmap(sim),
+                                 f"bucket_{algorithm}_m{m_pad}"))
 
-    ``make_sim(m_pad)`` must return a closure ``sim(m) -> (n_evals,)`` that
-    is numerically independent of ``m_pad`` for any ``m <= m_pad`` (shared
-    draws sliced, reductions masked) — that contract is what makes the
-    three execution modes here interchangeable.
+
+def _run_grid(make_sim, ms, use_vmap: bool, bucketed: bool,
+              algorithm: str):
+    """Run the grid's simulations; rows follow ``ms`` order.
+
+    ``make_sim(m_pad)`` must return ``(sim, consts)``: a closure
+    ``sim(m) -> (n_evals,)`` that is numerically independent of ``m_pad``
+    for any ``m <= m_pad`` (shared draws sliced, reductions masked) — that
+    contract is what makes the three execution modes here interchangeable
+    — and the arrays it closes over, whose bytes the ``bucket`` span
+    reports as ``const_bytes``.
     """
     m_top = max(ms)
     if not use_vmap:
         _note_pad_waste([(m, m_top) for m in ms])
-        jsim = _jit(make_sim(m_top))      # one compile serves every m
+        jsim = _jit(make_sim(m_top)[0])   # one compile serves every m
         return jnp.stack([
-            instrument.timed_call(jsim, m, span_name="grid_member",
-                                  m=int(m), m_pad=m_top)
+            instrument.dispatch(jsim, m, span_name="grid_member",
+                                m=int(m), m_pad=m_top)
             for m in jnp.asarray(ms, jnp.int32)])
-    if not bucketed:
-        _note_pad_waste([(m, m_top) for m in ms])
-        return instrument.dispatch(
-            _jit(jax.vmap(make_sim(m_top))), jnp.asarray(ms, jnp.int32),
-            span_name="bucket", m_pad=m_top, members=len(ms))
-    buckets = _buckets(ms)
+    buckets = (_buckets(ms) if bucketed
+               else [(tuple(range(len(ms))), m_top)])
     _note_pad_waste([(ms[i], m_pad) for pos, m_pad in buckets for i in pos])
     rows = [None] * len(ms)
     for pos, m_pad in buckets:
-        sub = jnp.asarray([ms[i] for i in pos], jnp.int32)
+        sim, consts = make_sim(m_pad)
         out = instrument.dispatch(
-            _jit(jax.vmap(make_sim(m_pad))), sub,
-            span_name="bucket", m_pad=m_pad, members=len(pos))
+            _bucket_program(sim, algorithm, m_pad),
+            jnp.asarray([ms[i] for i in pos], jnp.int32),
+            span_name="bucket", algorithm=algorithm, m_pad=m_pad,
+            members=len(pos), const_bytes=instrument.nbytes(consts))
+        if pos == tuple(range(len(ms))):
+            return out                    # one bucket, already in order
         for k, i in enumerate(pos):
             rows[i] = out[k]
     return jnp.stack(rows)
@@ -292,12 +303,16 @@ def sweep(algorithm: Union[str, alg_base.Algorithm], train, test,
 
         return sim_with
 
+    # the dataset arrays every simulation closes over (besides its draws)
+    data = (train.X, train.y, Xte, yte)
+
     def make_sim(m_pad):
         sim_with = make_sim_with(m_pad)
         subs = [alg.slice_draws(d, m_pad) for d in draws_by_seed]
 
         if n_seeds == 1:
-            return sim_with(subs[0])       # the exact ENGINE_VERSION-3 path
+            # the exact ENGINE_VERSION-3 path
+            return sim_with(subs[0]), (data, subs)
 
         stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *subs)
 
@@ -307,7 +322,7 @@ def sweep(algorithm: Union[str, alg_base.Algorithm], train, test,
             # (seeds x m) block is one trace / one compile per bucket
             return jax.vmap(lambda sub: sim_with(sub)(m))(stacked)
 
-        return sim_seeded
+        return sim_seeded, (data, stacked)
 
     def make_sim_elem(m_pad):
         # distributed twin of `make_sim`: one simulation per (m, seed)
@@ -322,7 +337,7 @@ def sweep(algorithm: Union[str, alg_base.Algorithm], train, test,
             sub = jax.tree.map(lambda a: a[s], stacked)
             return sim_with(sub)(m)
 
-        return sim_elem
+        return sim_elem, (data, stacked)
 
     if bucketed is None:
         bucketed = alg.bucketed_default
@@ -337,11 +352,12 @@ def sweep(algorithm: Union[str, alg_base.Algorithm], train, test,
             _note_pad_waste([(ms[i], m_pad)
                              for pos, m_pad in buckets for i in pos])
             losses = dist_partition.run_grid_sharded(
-                make_sim_elem, ms, n_seeds, dmesh, buckets, jit_fn=_jit)
+                make_sim_elem, ms, n_seeds, dmesh, buckets, jit_fn=_jit,
+                algorithm=alg.name)
         else:
-            losses = _run_grid(make_sim, ms, use_vmap, bucketed)
-    return _losses_dict(alg.name, ms, losses, iters, eval_every,
-                        problem=prob.name, n_seeds=n_seeds)
+            losses = _run_grid(make_sim, ms, use_vmap, bucketed, alg.name)
+        return _losses_dict(alg.name, ms, losses, iters, eval_every,
+                            problem=prob.name, n_seeds=n_seeds)
 
 
 def run_algorithm_sweep(algorithm: str, train, test, ms, *, iters,

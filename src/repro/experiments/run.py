@@ -28,7 +28,8 @@ m_max comparison whenever the spec produces both sides.  The exit status
 is 1 when any job ends ``failed`` (every attempt raised).
 
 ``--trace out.json`` records the run as nested spans (sweep -> job ->
-bucket -> lower/compile/execute, journal and cache IO) and writes
+grid -> bucket -> jaxpr_trace/lower/compile, fetch, journal and cache
+IO) and writes
 Chrome-trace / Perfetto JSON — load it at https://ui.perfetto.dev or
 summarize with ``python -m repro.telemetry --summarize out.json``.
 ``--metrics`` dumps the process metrics registry (Prometheus text) after

@@ -368,22 +368,32 @@ def _compute_sweep_inner(spec: SweepSpec, fp: str, cache_dir: str, *,
                     "spec": spec_mod.computational_dict(spec),
                     "datasets": {}, "jobs": {}}
 
+    datasets, splits = {}, {}
     with trace.span("datasets", count=len(spec.datasets)):
-        datasets = {name: spec_mod.build_dataset(ds)
-                    for name, ds in spec.datasets.items()}
-        splits = {name: spec_mod.split_dataset(spec.datasets[name], data,
-                                               spec.split_seed)
-                  for name, data in datasets.items()}
+        # every build is dispatched before the first split (a split can
+        # wait on its dataset), so the host compiles each dataset's
+        # program while the device runs the last: a dataset has one
+        # dataset_build span for its build and one for its split
+        for name, ds in spec.datasets.items():
+            with trace.span("dataset_build", name=name, part="build") as sp:
+                data = datasets[name] = spec_mod.build_dataset(ds)
+                sp.set(n=data.X.shape[0], d=data.X.shape[1])
+        for name, data in datasets.items():
+            with trace.span("dataset_build", name=name, part="split",
+                            n=data.X.shape[0], d=data.X.shape[1]):
+                splits[name] = spec_mod.split_dataset(
+                    spec.datasets[name], data, spec.split_seed)
 
         for name, data in datasets.items():
             info: Dict = {"n": int(data.X.shape[0]),
                           "d": int(data.X.shape[1])}
-            if spec.measure_csim > 0:
-                info["csim"] = MX.csim(data.X[:spec.csim_rows],
-                                       spec.measure_csim)
-            # every dataset self-reports its §IV characters into the result
-            rows = spec.characters_rows or DEFAULT_CHARACTERS_ROWS
-            info["characters"] = MX.summarize(data.X[:rows])
+            with trace.span("characters", name=name):
+                if spec.measure_csim > 0:
+                    info["csim"] = MX.csim(data.X[:spec.csim_rows],
+                                           spec.measure_csim)
+                # every dataset self-reports its §IV characters
+                rows = spec.characters_rows or DEFAULT_CHARACTERS_ROWS
+                info["characters"] = MX.summarize(data.X[:rows])
             result["datasets"][name] = info
 
     for job in spec.jobs:

@@ -4,8 +4,9 @@ Three pieces (docs/observability.md):
 
   * `repro.telemetry.trace` — a context-var span tracer.  Off by
     default; `trace.start()` installs it, instrumented hot paths then
-    emit nested spans (sweep -> job -> bucket -> lower/compile/execute,
-    journal/cache IO, service tiers), and `trace.export(path)` writes
+    emit nested spans (sweep -> job -> grid -> bucket ->
+    jaxpr_trace/lower/compile, fetch, journal/cache IO, service tiers),
+    and `trace.export(path)` writes
     Chrome-trace / Perfetto JSON.  With tracing off every `span()` is a
     shared no-op — the observational contract: the sweep path executes
     the same code and produces byte-identical artifacts either way.
@@ -15,9 +16,9 @@ Three pieces (docs/observability.md):
     ``engine.JIT_CALLS`` and ``runner.SWEEP_COMPUTES`` are now
     registry-backed read aliases (existing reads stay source-
     compatible; increments are locked).
-  * `repro.telemetry.instrument` — jax-aware helpers, notably the
-    per-bucket compile-vs-execute dispatch split (AOT lower/compile,
-    bit-identical results).
+  * `repro.telemetry.instrument` — jax-aware helpers: JAX's compile
+    events as spans (while tracing) and as always-on counters, the
+    profiler annotation of every live span, named bucket programs.
 
 CLI: ``python -m repro.telemetry`` dumps the process registry;
 ``--summarize trace.json`` validates + phase-breaks a saved trace.

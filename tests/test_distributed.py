@@ -83,7 +83,7 @@ def test_run_grid_sharded_matches_direct_eval():
         def sim_elem(m, s):
             return jnp.stack([m.astype(jnp.float32), s.astype(jnp.float32),
                               jnp.float32(m_pad)])
-        return sim_elem
+        return sim_elem, ()
 
     for n_seeds in (1, 3):
         for buckets in (engine._buckets(ms),
@@ -350,8 +350,8 @@ TRACED_RACE = """
     tracer = trace.stop()
 
     # the observational contract under shard_map + donated buffers:
-    # the traced lower/compile/execute split runs the same executable,
-    # so the curves are exactly equal
+    # the traced race runs the same jit call, so the curves are exactly
+    # equal
     np.testing.assert_array_equal(np.asarray(traced["losses"]),
                                   np.asarray(base["losses"]))
 
@@ -360,7 +360,8 @@ TRACED_RACE = """
         "repro_distributed_psum_rounds_total").value - c0
     assert delta == traced["psum_rounds"], (delta, traced["psum_rounds"])
 
-    # the race span carries its AOT children inside its interval
+    # the race span holds its program's compile phases, and the fetch
+    # that waits for the device follows it
     evs = tracer.events
     races = [e for e in evs if e["name"] == "race"]
     assert len(races) == 1
@@ -369,9 +370,13 @@ TRACED_RACE = """
     assert r["args"]["sync_every"] == 2
     inside = [e["name"] for e in evs
               if e is not r and e["ts"] >= r["ts"] - 1e-6
-              and e["ts"] + e["dur"] <= r["ts"] + r["dur"] + 1e-6]
-    for child in ("lower", "compile", "execute"):
+              and e["ts"] + e["dur"] <= r["ts"] + r["dur"] + 1e-6
+              and e["args"]["depth"] == r["args"]["depth"] + 1]
+    for child in ("jaxpr_trace", "lower", "compile"):
         assert child in inside, (child, inside)
+    assert "execute" not in inside
+    assert any(e["name"] == "fetch" and e["ts"] >= r["ts"] + r["dur"]
+               for e in evs)
 
     # and the recorder mirrored both the span and the race event
     snap = RECORDER.snapshot()
@@ -386,7 +391,8 @@ TRACED_RACE = """
 @pytest.mark.slow
 def test_traced_race_subprocess():
     """Tracing the racing path on 8 virtual devices: exactly-equal
-    curves, the race span's lower/compile/execute children, and live
-    psum accounting — telemetry survives shard_map + donation."""
+    curves, the race span's compile-phase children and the fetch after
+    it, and live psum accounting — telemetry survives shard_map +
+    donation."""
     out = _run_sub(TRACED_RACE, timeout=420)
     assert "TRACED_RACE_OK" in out

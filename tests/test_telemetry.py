@@ -126,6 +126,48 @@ def test_spans_nest_per_thread():
         assert len({e["tid"] for e in mine}) == 1
 
 
+_FORK_PID_SCRIPT = """
+import json, os, sys
+from repro.telemetry import trace
+trace.start()
+r, w = os.pipe()
+child = os.fork()
+if child == 0:
+    with trace.span("child"):
+        pass
+    ev = [e for e in trace.active().events if e["name"] == "child"][0]
+    os.write(w, json.dumps([os.getpid(), ev["pid"]]).encode())
+    os._exit(0)
+os.close(w)
+got = json.loads(os.read(r, 256))
+os.waitpid(child, 0)
+with trace.span("parent"):
+    pass
+ev = [e for e in trace.stop().events if e["name"] == "parent"][0]
+print(json.dumps({"child": got, "parent": [os.getpid(), ev["pid"]]}))
+"""
+
+
+def test_span_pid_is_the_recording_process():
+    """Spans carry the id of the process that records them, in the parent
+    and in a child forked while a tracer runs (the id is read once per
+    process, not per span).  Run in a fresh interpreter without jax, which
+    must not fork once its threads are up."""
+    import os
+    import pathlib
+    import subprocess
+    import sys
+    src = str(pathlib.Path(trace.__file__).parents[2])
+    env = dict(os.environ, PYTHONPATH=src)
+    out = subprocess.run([sys.executable, "-c", _FORK_PID_SCRIPT], env=env,
+                         capture_output=True, text=True, check=True,
+                         timeout=60)
+    got = json.loads(out.stdout)
+    for real, recorded in got.values():
+        assert recorded == real
+    assert got["child"][0] != got["parent"][0]
+
+
 def test_phase_breakdown_coverage_math():
     """Union coverage merges overlaps; the root's own span is excluded
     from the phase table."""
@@ -374,8 +416,8 @@ def test_jit_calls_alias_counts_cold_vs_cached(tmp_path):
     assert engine.JIT_CALLS - j0 == 0
     assert runner.SWEEP_COMPUTES - s0 == 0
 
-    # tracing ON changes neither count (dispatch AOT is still one
-    # wrapper, counted at jit-wrap time)
+    # tracing ON changes neither count (one wrapper per bucket, counted
+    # at jit-wrap time)
     trace.start()
     j0 = engine.JIT_CALLS
     runner.run_sweep(spec, cache_dir=str(tmp_path / "cache2"))
@@ -396,8 +438,8 @@ def test_module_getattr_raises_for_unknown():
 
 def test_artifact_bytes_identical_with_tracing(tmp_path):
     """Acceptance: artifacts are byte-identical with telemetry on vs off
-    — the AOT lower/compile/execute split produces the same executable
-    from the same lowering, and no telemetry state enters the payload."""
+    — a traced sweep runs the same jit calls, and no telemetry state
+    enters the payload."""
     spec = tiny_spec("tel_bytes", ms=(1, 2, 4),
                      epsilon=EpsilonSpec(probe_m=2))
     fp = spec_mod.fingerprint(spec)
@@ -416,7 +458,8 @@ def test_artifact_bytes_identical_with_tracing(tmp_path):
 
 def test_trace_covers_sweep_with_bucket_split(tmp_path):
     """Acceptance: a traced sweep's root span attributes >=95% of the
-    traced wall-clock, with per-bucket compile/execute children."""
+    traced wall-clock; each bucket span holds the lower and compile of its
+    program, and each grid holds the fetch that waits for the device."""
     spec = tiny_spec("tel_cov", ms=(1, 2, 4))
     trace.start()
     runner.run_sweep(spec, cache_dir=str(tmp_path / "c"))
@@ -424,21 +467,153 @@ def test_trace_covers_sweep_with_bucket_split(tmp_path):
     path = trace.export(str(tmp_path / "trace.json"))
     evs = json.load(open(path))["traceEvents"]
     names = {e["name"] for e in evs}
-    assert {"sweep", "job", "grid", "bucket", "lower", "compile",
-            "execute", "store"} <= names
+    assert {"sweep", "datasets", "dataset_build", "characters", "job",
+            "grid", "bucket", "jaxpr_trace", "lower", "compile", "fetch",
+            "store"} <= names
+    assert "execute" not in names
     overall = trace.phase_breakdown(evs)
     assert overall["coverage"] >= 0.95
     scoped = trace.phase_breakdown(evs, root="sweep")
     assert scoped["root"] == "sweep"
-    # every bucket span has compile+execute children inside its interval
+
+    def children(parent):
+        return [e["name"] for e in evs
+                if e["ts"] >= parent["ts"] - 1e-6
+                and e["ts"] + e["dur"] <= parent["ts"] + parent["dur"] + 1e-6
+                and e["args"]["depth"] == parent["args"]["depth"] + 1]
+
     buckets = [e for e in evs if e["name"] == "bucket"]
     assert buckets
     for b in buckets:
-        inside = [e["name"] for e in evs
-                  if e["ts"] >= b["ts"] - 1e-6
-                  and e["ts"] + e["dur"] <= b["ts"] + b["dur"] + 1e-6
-                  and e["args"]["depth"] == b["args"]["depth"] + 1]
-        assert "compile" in inside and "execute" in inside
+        inside = children(b)
+        assert "compile" in inside and "lower" in inside
+    for g in (e for e in evs if e["name"] == "grid"):
+        assert "fetch" in children(g)
+
+
+def test_compile_phase_spans_nest_in_their_bucket_with_fun_name(tmp_path):
+    """JAX's compile events become spans one level below the bucket that
+    triggered them, named for the bucket's program."""
+    spec = tiny_spec("tel_phases", ms=(1, 2, 4))
+    tracer = trace.start()
+    runner.run_sweep(spec, cache_dir=str(tmp_path / "c"))
+    trace.stop()
+    evs = tracer.events
+    buckets = [e for e in evs if e["name"] == "bucket"]
+    assert [(b["args"]["algorithm"], b["args"]["m_pad"]) for b in buckets] \
+        == [("minibatch", 2), ("minibatch", 4)]
+    for b in buckets:
+        program = f"bucket_minibatch_m{b['args']['m_pad']}"
+        lo, hi = b["ts"], b["ts"] + b["dur"]
+        mine = {e["name"]: e for e in evs
+                if e["args"].get("fun_name") in (program, f"jit({program})")}
+        assert set(mine) == {"jaxpr_trace", "lower", "compile"}
+        for e in mine.values():
+            assert lo <= e["ts"] and e["ts"] + e["dur"] <= hi
+        assert mine["compile"]["args"]["depth"] == b["args"]["depth"] + 1
+        assert mine["lower"]["args"]["depth"] == b["args"]["depth"] + 1
+    # const_bytes: the train and test arrays, plus draws that widen with
+    # the bucket's m_pad, counted from shapes
+    ds = spec.datasets["d0"]
+    tr, te = spec_mod.split_dataset(ds, spec_mod.build_dataset(ds),
+                                    spec.split_seed)
+    data = sum(a.nbytes for a in (tr.X, tr.y, te.X, te.y))
+    small, wide = (b["args"]["const_bytes"] for b in buckets)
+    assert data < small < wide
+    # a jaxpr trace nested in another sits one level below it
+    top = next(e for e in evs if e["name"] == "jaxpr_trace"
+               and e["args"]["fun_name"] == "bucket_minibatch_m2")
+    inner = [e for e in evs if e["name"] == "jaxpr_trace"
+             and top["ts"] <= e["ts"] and e is not top
+             and e["ts"] + e["dur"] <= top["ts"] + top["dur"]]
+    assert inner
+    assert all(e["args"]["depth"] > top["args"]["depth"] for e in inner)
+
+
+@pytest.mark.parametrize("traced", [False, True], ids=["off", "on"])
+def test_jax_compile_counter_matches_backend_events(traced, tmp_path):
+    """repro_jax_compiles_total rises by exactly the number of JAX
+    backend-compile events, whether or not a tracer runs; the phase
+    seconds rise with them."""
+    seen = []
+
+    def on(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            seen.append(duration)
+
+    compiles = metrics.REGISTRY.counter("repro_jax_compiles_total")
+    seconds = metrics.REGISTRY.counter("repro_jax_compile_seconds_total",
+                                       labels={"phase": "compile"})
+    c0, s0 = compiles.value, seconds.value
+    jax.monitoring.register_event_duration_secs_listener(on)
+    try:
+        if traced:
+            trace.start()
+        runner.run_sweep(tiny_spec(f"tel_count_{traced}", ms=(1, 3)),
+                         cache_dir=str(tmp_path / "c"))
+    finally:
+        trace.stop()
+        jax.monitoring.unregister_event_duration_listener(on)
+    assert seen
+    assert compiles.value - c0 == len(seen)
+    assert seconds.value - s0 == pytest.approx(sum(seen))
+
+
+def test_tracing_off_makes_no_span_or_annotation(tmp_path, monkeypatch):
+    """With no tracer, spans are the shared no-op, no annotation is
+    entered and no compile event becomes a span; trace.start() enters
+    one anchor annotation and labels each span's annotation."""
+    labels = []
+
+    class Recorded:
+        def __init__(self, label):
+            labels.append(label)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(trace, "_ANNOTATION", Recorded)
+    seq0 = RECORDER.snapshot()["seq"]
+    assert trace.span("bucket", algorithm="minibatch", m_pad=8) \
+        is trace.span("other")
+    runner.run_sweep(tiny_spec("tel_quiet"), cache_dir=str(tmp_path / "c"))
+    assert labels == []
+    assert RECORDER.snapshot(since=seq0)["spans"] == []
+
+    tracer = trace.start()
+    with trace.span("bucket", algorithm="minibatch", m_pad=8):
+        pass
+    trace.stop()
+    assert labels == [trace.ANCHOR, "bucket:minibatch/m8"]
+    other = tracer.payload()["otherData"]
+    assert other["anchor"] == trace.ANCHOR
+    assert other["t0_ns"] <= other["anchor_ns"]
+
+
+def test_bucket_programs_are_named(tmp_path):
+    """The engine jits each bucket as bucket_<algorithm>_m<m_pad>, so the
+    profiler's modules line reads jit_bucket_<algorithm>_m<m_pad>."""
+    names = []
+
+    def on(event, duration, fun_name="", **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            names.append(fun_name)
+
+    jax.monitoring.register_event_duration_secs_listener(on)
+    try:
+        runner.run_sweep(
+            tiny_spec("tel_names", ms=(1, 2, 4),
+                      jobs=(JobSpec("minibatch", "d0"),
+                            JobSpec("hogwild", "d0"))),
+            cache_dir=str(tmp_path / "c"))
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on)
+    buckets = sorted(n for n in names if n.startswith("jit(bucket_"))
+    assert buckets == ["jit(bucket_hogwild_m4)", "jit(bucket_minibatch_m2)",
+                       "jit(bucket_minibatch_m4)"]
 
 
 def test_sequential_path_identical_traced(tmp_path):
