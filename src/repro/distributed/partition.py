@@ -17,16 +17,18 @@ docs/distributed.md for the contract).
   2. pads that axis to a multiple of the device count by repeating the
      first element (cheapest correct filler; the rows are dropped after),
   3. lays the padded ``(m, s)`` index arrays over the mesh's ``'shard'``
-     axis with :class:`jax.sharding.NamedSharding` and dispatches ONE
-     jitted vmap — computation follows the input sharding, so XLA splits
-     the batch across devices while constants (dataset, draws) replicate,
+     axis with :class:`jax.sharding.NamedSharding`, replicates the other
+     arguments (dataset, draws) on every device and dispatches ONE jitted
+     vmap — computation follows the input sharding, so XLA splits the
+     batch across devices,
   4. gathers, drops the padding rows, and scatters results back to grid
      order.
 
-One jit per bucket, exactly like the unsharded path — the compile count
-per mesh stays 1 per bucket (`scripts/bench_engine.py` measures this in
-BENCH_5.json).  The engine owns bucket policy and jit accounting; both
-arrive as arguments, which keeps this module free of engine imports.
+One jit per bucket signature, exactly like the unsharded path — the
+engine keeps the program per process, so a later sweep of the same shapes
+on the same mesh compiles nothing.  The engine owns bucket policy and its
+program cache; both arrive as arguments, which keeps this module free of
+engine imports.
 """
 
 from __future__ import annotations
@@ -64,21 +66,27 @@ def element_plan(pos: Sequence[int], ms: Sequence[int], n_seeds: int,
             n_real)
 
 
+def _fresh_program(m_pad, args, build):
+    return jax.jit(build()), False
+
+
 def run_grid_sharded(make_sim_elem: Callable, ms: Sequence[int],
                      n_seeds: int, dmesh: DeviceMesh,
                      buckets: List[Tuple[Tuple[int, ...], int]],
-                     jit_fn: Callable = jax.jit,
+                     program: Callable = _fresh_program,
                      algorithm: str = "sim") -> jnp.ndarray:
     """Run the whole grid sharded over ``dmesh``; rows follow ``ms`` order.
 
-    ``make_sim_elem(m_pad)`` must return ``(sim_elem, consts)``:
-    ``sim_elem(m, s) -> (n_evals,)`` obeying the engine's masked-simulation
-    contract (numerics independent of ``m_pad`` for any ``m <= m_pad``),
-    and the arrays it closes over (their bytes are the ``mesh_bucket``
-    span's ``const_bytes``); ``buckets`` is the engine's
-    ``[(positions, m_pad), ...]`` partition (a single flat bucket for
-    ``force_flat`` algorithms).  ``jit_fn`` is injected so the engine's
-    ``JIT_CALLS`` compile accounting covers the sharded path too; each
+    ``make_sim_elem(m_pad)`` must return ``(sim_elem, args)``:
+    ``sim_elem(m, s, *args) -> (n_evals,)`` obeying the engine's
+    masked-simulation contract (numerics independent of ``m_pad`` for any
+    ``m <= m_pad``), and the arrays it takes besides ``(m, s)``, which are
+    replicated on every device (their bytes are the ``mesh_bucket``
+    span's ``arg_bytes``); ``buckets`` is the engine's ``[(positions,
+    m_pad), ...]`` partition (a single flat bucket for ``force_flat``
+    algorithms).  ``program(m_pad, args, build)`` returns ``(jitted,
+    cached)``: the jit of ``build()``, which the engine keeps per process
+    (and counts in ``JIT_CALLS``) and the default builds afresh.  Each
     bucket's program is named ``bucket_<algorithm>_m<m_pad>``.
 
     Returns ``(S, n_evals)`` for ``n_seeds == 1``, else
@@ -90,17 +98,26 @@ def run_grid_sharded(make_sim_elem: Callable, ms: Sequence[int],
     for pos, m_pad in buckets:
         m_idx, s_idx, n_real = element_plan(pos, ms, n_seeds,
                                             dmesh.n_devices)
+        sim_elem, args = make_sim_elem(m_pad)
+
+        def build():
+            # vmapped over the flat (m, s) element axis; every element
+            # shares the other arguments
+            return instrument.named(
+                jax.vmap(sim_elem, in_axes=(0, 0) + (None,) * len(args)),
+                f"bucket_{algorithm}_m{m_pad}")
+
+        jfn, cached = program(m_pad, args, build)
         with trace.span("shard_put", devices=dmesh.n_devices,
                         elements=len(m_idx)):
             m_arr = jax.device_put(m_idx, sharded)
             s_arr = jax.device_put(s_idx, sharded)
-        sim_elem, consts = make_sim_elem(m_pad)
-        program = instrument.named(jax.vmap(sim_elem),
-                                   f"bucket_{algorithm}_m{m_pad}")
+            args = jax.device_put(args, dmesh.replicated())
         out = instrument.dispatch(
-            jit_fn(program), m_arr, s_arr, span_name="mesh_bucket",
+            jfn, m_arr, s_arr, *args, span_name="mesh_bucket",
             algorithm=algorithm, m_pad=m_pad, devices=dmesh.n_devices,
-            elements=len(m_idx), const_bytes=instrument.nbytes(consts))
+            elements=len(m_idx), cached=cached,
+            arg_bytes=instrument.nbytes(args))
         with trace.span("gather", elements=n_real):
             out = np.asarray(jax.device_get(out))[:n_real]
         out = out.reshape(len(pos), n_seeds, -1)

@@ -22,8 +22,22 @@ The masked-simulation contract (unchanged from ENGINE_VERSION 2):
     ``m_top = max(ms)`` and sliced per padding width — sweep member m
     consumes the first m columns no matter which bucket it lands in, so
     numerics are identical across flat / bucketed / sequential execution;
-  * each bucket of the grid then runs as ``jax.vmap(sim)(ms_bucket)`` —
-    one trace, one compile, one `lax.scan` pipeline per bucket.
+  * each bucket of the grid then runs as ``jax.vmap(sim)(ms_bucket, data,
+    draws)`` — one trace, one compile per bucket signature, per process,
+    and one `lax.scan` pipeline per bucket.
+
+**Programs kept per process** (`_program`): a simulation is a pure
+function of ``(m, data, draws)``, where ``data = (X, y, Xte, yte)`` and
+``draws`` are the bucket's sliced (seed-stacked) draws, passed as jit
+arguments rather than closed over as constants.  Its closure holds only
+static values — the frozen `Algorithm` and `Problem` instances, ``m_pad``,
+``iters``, ``eval_every``, ``n_seeds`` — so the jitted program is kept in
+a bounded, locked LRU (`PROGRAM_CACHE_SIZE`) keyed on those, the mode
+(vmapped, sequential or a mesh) and the shapes and dtypes of its
+arguments.  A hit skips trace, lowering and compile: same-shape datasets
+of one spec share a program, and later sweeps of the same shapes build
+nothing.  ``JIT_CALLS`` counts the misses;
+``repro_engine_program_cache_total{outcome}`` counts both.
 
 **Bucketed padding** (`_buckets`): a flat padded grid does S * work(m_top)
 FLOPs, so wide grids like [1, 2, 4, ..., 64] pay work(64) for the m=1
@@ -49,10 +63,10 @@ over independent draw sequences — `Algorithm.make_draws` is called once
 per seed (seed 0 with the caller's key, bit-identical to the
 ENGINE_VERSION-3 single-seed run; seed s with ``fold_in(key, s)``), the
 per-seed draws are stacked, and the per-m simulation is ``jax.vmap``-ed
-over that stacked axis *inside* ``sim(m)``.  The m-grid vmap then wraps
+over that stacked axis *inside* ``sim``.  The m-grid vmap then wraps
 the seed vmap, so the whole (seeds x m) grid is still ONE trace and ONE
-compile per bucket — no per-seed recompiles (`scripts/bench_engine.py`
-measures this via `JIT_CALLS` in BENCH_5.json).  Results keep ``losses``
+compile per bucket signature — no per-seed recompiles (`JIT_CALLS`
+counts them).  Results keep ``losses``
 as the seed-0 rows (every legacy consumer unchanged) and add
 ``losses_seeds`` — the full (S, n_seeds, n_evals) block `repro.analysis.
 stats` turns into mean/CI curves and bootstrap m_max distributions.
@@ -72,6 +86,9 @@ is bit-exact with ENGINE_VERSION 4.  The sequential reference path
 
 from __future__ import annotations
 
+import collections
+import threading
+import types
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
@@ -81,6 +98,7 @@ from repro.core import problems as problems_mod
 from repro.core.algorithms import base as alg_base
 from repro.core.algorithms import run_hogwild
 from repro.core.algorithms.lr import LAMBDA
+from repro.data import synth
 from repro.distributed import mesh as dist_mesh
 from repro.distributed import partition as dist_partition
 from repro.telemetry import instrument, metrics, recorder, trace
@@ -89,8 +107,18 @@ from repro.telemetry import instrument, metrics, recorder, trace
 #: is at most this multiple of the smallest member.
 MAX_PAD_RATIO = 2.0
 
-#: Counts `jax.jit` wrappers actually dispatched by `_run_grid` — each one
-#: is traced and compiled exactly once here, so this is the engine's
+#: Most jitted programs `_program` keeps per process, least recently used
+#: dropped first.  One ``ls`` sweep builds 6, a Table II sweep about 20.
+PROGRAM_CACHE_SIZE = 64
+
+#: signature -> jitted program (`_program`); the service sweeps from
+#: several threads, so every access holds the lock
+_PROGRAMS: "collections.OrderedDict[tuple, object]" = \
+    collections.OrderedDict()
+_PROGRAMS_LOCK = threading.Lock()
+
+#: Counts the `jax.jit` wrappers the engine builds — program-cache misses;
+#: each one is traced and compiled exactly once, so this is the engine's
 #: compile count.  Registry-backed (PR 9): increments are locked so the
 #: multi-threaded service counts exactly; the module-level ``JIT_CALLS``
 #: read (`scripts/bench_engine.py` snapshots, tests) stays source-
@@ -98,6 +126,14 @@ MAX_PAD_RATIO = 2.0
 _JIT_CALLS = metrics.counter(
     "repro_engine_jit_compiles_total",
     help="jax.jit wrappers dispatched by the engine (one XLA compile each)")
+
+#: One increment per program lookup: ``hit`` reuses a kept program (no
+#: trace, lowering or compile), ``miss`` builds one.
+_PROGRAM_CACHE = {
+    outcome: metrics.counter(
+        "repro_engine_program_cache_total", labels={"outcome": outcome},
+        help="engine program lookups, by whether a kept program served")
+    for outcome in ("hit", "miss")}
 
 #: Fraction of the last vmapped grid's padded worker-axis FLOPs that were
 #: padding waste: 1 - sum(m) / sum(m_pad per member).  0 for a perfectly
@@ -183,30 +219,132 @@ def _buckets(ms: Sequence[int],
     return out
 
 
-def _bucket_program(sim, algorithm: str, m_pad: int):
-    """The vmapped simulation, named ``bucket_<algorithm>_m<m_pad>`` (the
-    trace's ``jit_bucket_...`` module) and jitted."""
-    return _jit(instrument.named(jax.vmap(sim),
-                                 f"bucket_{algorithm}_m{m_pad}"))
+def _simulation(alg, prob, m_pad: int, eval_every: int, n_evals: int):
+    """``sim(m, data, sub) -> (n_evals,)``: ``alg`` on ``prob`` at pad
+    width ``m_pad`` for live worker count ``m``, over ``data = (X, y, Xte,
+    yte)`` and the draws ``sub`` sliced to ``m_pad``.  It closes over
+    static values only, so one program serves every dataset and draw of
+    the same shapes."""
+    def sim(m, data, sub):
+        X, y, Xte, yte = data
+        train = synth.Dataset(X, y)
+        ctx = alg_base.SimContext(m, m_pad)
+        state0 = alg.init_state(prob, train, ctx)
+
+        def step(state, inp):
+            batch, t = inp
+            return alg.step(prob, train, ctx, state, batch, t), None
+
+        def outer(state, e):
+            base = e * eval_every
+            ts = base + jnp.arange(eval_every)
+            bsl = jax.tree.map(lambda a: jax.lax.dynamic_slice_in_dim(
+                a, base, eval_every, axis=0), sub)
+            state, _ = jax.lax.scan(step, state, (bsl, ts))
+            return state, prob.test_loss(alg.readout(ctx, state), Xte, yte)
+
+        _, losses = jax.lax.scan(outer, state0, jnp.arange(n_evals))
+        return losses
+
+    return sim
 
 
-def _run_grid(make_sim, ms, use_vmap: bool, bucketed: bool,
-              algorithm: str):
+def _seeded(sim, n_seeds: int):
+    """``sim`` over draws stacked on a leading seed axis when ``n_seeds >
+    1``: the per-seed simulation vmapped inside, so the m-grid vmap wraps
+    it and the whole (seeds x m) block is one program."""
+    if n_seeds == 1:
+        return sim
+
+    def sim_seeded(m, data, stacked):
+        return jax.vmap(lambda sub: sim(m, data, sub))(stacked)
+
+    return sim_seeded
+
+
+def _signature(tree):
+    """What JAX specializes a program on besides its static closure: the
+    tree structure and each leaf's shape, dtype and weak type, plus the
+    default device and matmul precision in effect."""
+    leaves, treedef = jax.tree.flatten(tree)
+    return (treedef, tuple(jax.typeof(x) for x in leaves),
+            jax.config.jax_default_device,
+            jax.config.jax_default_matmul_precision)
+
+
+def _code(*objs) -> tuple:
+    """The functions a program built from ``objs`` calls, as they resolve
+    now: each function on each object's class, and each module-level
+    callable that their code (nested functions included) names.  A method
+    or function replaced at run time — a patched method, a planted fault —
+    thus keys a new program instead of reusing one traced from the old
+    code."""
+    found = []
+
+    def named(code, glb):
+        for name in code.co_names:
+            value = glb.get(name)
+            if callable(value):
+                found.append(value)
+        for const in code.co_consts:
+            if isinstance(const, types.CodeType):
+                named(const, glb)
+
+    for obj in objs:
+        cls = type(obj)
+        for attr in dir(cls):
+            fn = getattr(cls, attr)
+            if isinstance(fn, types.FunctionType):
+                found.append(fn)
+                named(fn.__code__, fn.__globals__)
+    return tuple(found)
+
+
+def _program(key, build):
+    """The jitted program for ``key``, kept per process; ``build()``
+    returns the function to jit on a miss.  Returns ``(program,
+    cached)``."""
+    try:
+        hash(key)
+    except TypeError:         # an array-valued hyperparameter: not kept
+        _PROGRAM_CACHE["miss"].inc()
+        return _jit(build()), False
+    with _PROGRAMS_LOCK:
+        program = _PROGRAMS.get(key)
+        if program is not None:
+            _PROGRAMS.move_to_end(key)
+            _PROGRAM_CACHE["hit"].inc()
+            return program, True
+        _PROGRAM_CACHE["miss"].inc()
+        program = _PROGRAMS[key] = _jit(build())
+        if len(_PROGRAMS) > PROGRAM_CACHE_SIZE:
+            _PROGRAMS.popitem(last=False)
+        return program, False
+
+
+def clear_programs() -> None:
+    """Drop every kept program, so the next sweep builds cold (tests)."""
+    with _PROGRAMS_LOCK:
+        _PROGRAMS.clear()
+
+
+def _run_grid(bucket, ms, use_vmap: bool, bucketed: bool, algorithm: str):
     """Run the grid's simulations; rows follow ``ms`` order.
 
-    ``make_sim(m_pad)`` must return ``(sim, consts)``: a closure
-    ``sim(m) -> (n_evals,)`` that is numerically independent of ``m_pad``
-    for any ``m <= m_pad`` (shared draws sliced, reductions masked) — that
+    ``bucket(m_pad, batched)`` must return ``(program, args, cached)``: a
+    jitted ``program(m, *args) -> (n_evals,)`` (with ``batched``, vmapped
+    over a vector of m) that is numerically independent of ``m_pad`` for
+    any ``m <= m_pad`` (shared draws sliced, reductions masked) — that
     contract is what makes the three execution modes here interchangeable
-    — and the arrays it closes over, whose bytes the ``bucket`` span
-    reports as ``const_bytes``.
+    — the arrays it takes, whose bytes the ``bucket`` span reports as
+    ``arg_bytes``, and whether the program was already built.
     """
     m_top = max(ms)
     if not use_vmap:
         _note_pad_waste([(m, m_top) for m in ms])
-        jsim = _jit(make_sim(m_top)[0])   # one compile serves every m
+        jsim, args, _ = bucket(m_top, False)   # one program serves every m
         return jnp.stack([
-            instrument.dispatch(jsim, m, span_name="grid_member",
+            instrument.dispatch(jsim, m, *args, span_name="grid_member",
                                 m=int(m), m_pad=m_top)
             for m in jnp.asarray(ms, jnp.int32)])
     buckets = (_buckets(ms) if bucketed
@@ -214,12 +352,12 @@ def _run_grid(make_sim, ms, use_vmap: bool, bucketed: bool,
     _note_pad_waste([(ms[i], m_pad) for pos, m_pad in buckets for i in pos])
     rows = [None] * len(ms)
     for pos, m_pad in buckets:
-        sim, consts = make_sim(m_pad)
+        program, args, cached = bucket(m_pad, True)
         out = instrument.dispatch(
-            _bucket_program(sim, algorithm, m_pad),
-            jnp.asarray([ms[i] for i in pos], jnp.int32),
+            program, jnp.asarray([ms[i] for i in pos], jnp.int32), *args,
             span_name="bucket", algorithm=algorithm, m_pad=m_pad,
-            members=len(pos), const_bytes=instrument.nbytes(consts))
+            members=len(pos), cached=cached,
+            arg_bytes=instrument.nbytes(args))
         if pos == tuple(range(len(ms))):
             return out                    # one bucket, already in order
         for k, i in enumerate(pos):
@@ -277,67 +415,50 @@ def sweep(algorithm: Union[str, alg_base.Algorithm], train, test,
                          for s in range(1, n_seeds)]
     draws_by_seed = [alg.make_draws(k, n, iters, m_top) for k in seed_keys]
 
-    def make_sim_with(m_pad):
-        def sim_with(sub):
-            def sim(m):
-                ctx = alg_base.SimContext(m, m_pad)
-                state0 = alg.init_state(prob, train, ctx)
-
-                def step(state, inp):
-                    batch, t = inp
-                    return alg.step(prob, train, ctx, state, batch, t), None
-
-                def outer(state, e):
-                    base = e * eval_every
-                    ts = base + jnp.arange(eval_every)
-                    bsl = jax.tree.map(lambda a: jax.lax.dynamic_slice_in_dim(
-                        a, base, eval_every, axis=0), sub)
-                    state, _ = jax.lax.scan(step, state, (bsl, ts))
-                    return state, prob.test_loss(alg.readout(ctx, state),
-                                                 Xte, yte)
-
-                _, losses = jax.lax.scan(outer, state0, jnp.arange(n_evals))
-                return losses
-
-            return sim
-
-        return sim_with
-
-    # the dataset arrays every simulation closes over (besides its draws)
+    # the dataset arrays every simulation takes (besides its draws)
     data = (train.X, train.y, Xte, yte)
 
-    def make_sim(m_pad):
-        sim_with = make_sim_with(m_pad)
+    def operands(m_pad, stack):
         subs = [alg.slice_draws(d, m_pad) for d in draws_by_seed]
+        if not stack:
+            return data, subs[0]          # the exact ENGINE_VERSION-3 path
+        return data, jax.tree.map(lambda *xs: jnp.stack(xs), *subs)
 
-        if n_seeds == 1:
-            # the exact ENGINE_VERSION-3 path
-            return sim_with(subs[0]), (data, subs)
+    code = _code(alg, prob)
 
-        stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *subs)
+    def signature(mode, m_pad, args):
+        return (mode, alg, prob, code, m_pad, iters, eval_every, n_seeds,
+                _signature(args))
 
-        def sim_seeded(m):
-            # vmap the per-seed simulation over the stacked draw axis: the
-            # m-grid vmap in `_run_grid` wraps this, so the whole
-            # (seeds x m) block is one trace / one compile per bucket
-            return jax.vmap(lambda sub: sim_with(sub)(m))(stacked)
+    def bucket(m_pad, batched):
+        args = operands(m_pad, n_seeds > 1)
 
-        return sim_seeded, (data, stacked)
+        def build():
+            sim = _seeded(_simulation(alg, prob, m_pad, eval_every, n_evals),
+                          n_seeds)
+            if not batched:
+                return sim
+            # vmapped over the bucket's m vector; data and draws are
+            # shared by every member
+            return instrument.named(jax.vmap(sim, in_axes=(0, None, None)),
+                                    f"bucket_{alg.name}_m{m_pad}")
+
+        program, cached = _program(
+            signature("vmap" if batched else "sequential", m_pad, args),
+            build)
+        return program, args, cached
 
     def make_sim_elem(m_pad):
-        # distributed twin of `make_sim`: one simulation per (m, seed)
-        # cell, with the seed's draws gathered by the traced index — the
+        # distributed twin of `bucket`: one simulation per (m, seed) cell,
+        # with the seed's draws gathered by the traced index — the
         # partitioner vmaps this over a flat element axis laid across the
         # mesh, so the seed axis shards exactly like the grid axis
-        sim_with = make_sim_with(m_pad)
-        subs = [alg.slice_draws(d, m_pad) for d in draws_by_seed]
-        stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *subs)
+        sim = _simulation(alg, prob, m_pad, eval_every, n_evals)
 
-        def sim_elem(m, s):
-            sub = jax.tree.map(lambda a: a[s], stacked)
-            return sim_with(sub)(m)
+        def sim_elem(m, s, data, stacked):
+            return sim(m, data, jax.tree.map(lambda a: a[s], stacked))
 
-        return sim_elem, (data, stacked)
+        return sim_elem, operands(m_pad, True)
 
     if bucketed is None:
         bucketed = alg.bucketed_default
@@ -352,10 +473,12 @@ def sweep(algorithm: Union[str, alg_base.Algorithm], train, test,
             _note_pad_waste([(ms[i], m_pad)
                              for pos, m_pad in buckets for i in pos])
             losses = dist_partition.run_grid_sharded(
-                make_sim_elem, ms, n_seeds, dmesh, buckets, jit_fn=_jit,
+                make_sim_elem, ms, n_seeds, dmesh, buckets,
+                program=lambda m_pad, args, build: _program(
+                    signature(("mesh", dmesh.devices), m_pad, args), build),
                 algorithm=alg.name)
         else:
-            losses = _run_grid(make_sim, ms, use_vmap, bucketed, alg.name)
+            losses = _run_grid(bucket, ms, use_vmap, bucketed, alg.name)
         return _losses_dict(alg.name, ms, losses, iters, eval_every,
                             problem=prob.name, n_seeds=n_seeds)
 

@@ -9,3 +9,13 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax
 
 jax.config.update("jax_enable_x64", False)
+
+import pytest
+
+
+@pytest.fixture
+def cold_programs():
+    """Drop the engine's kept programs, so the test's first sweep traces,
+    lowers and compiles every bucket program afresh."""
+    from repro.experiments import engine
+    engine.clear_programs()

@@ -194,7 +194,7 @@ def test_seeded_replicates_match_independent_keyed_runs():
             rtol=2e-4, atol=2e-5)
 
 
-def test_seeded_grid_compiles_once_per_bucket():
+def test_seeded_grid_compiles_once_per_bucket(cold_programs):
     """Acceptance: n_seeds=8 runs as ONE vmapped trace — the jit count
     equals the bucket count, exactly as for a single seed."""
     ds = synth.make_higgs_like(KEY, n=120, d=8)

@@ -398,10 +398,11 @@ def test_race_publishes_psum_event():
 # legacy counter parity (engine.JIT_CALLS / runner.SWEEP_COMPUTES aliases)
 # ---------------------------------------------------------------------------
 
-def test_jit_calls_alias_counts_cold_vs_cached(tmp_path):
-    """The registry-backed engine.JIT_CALLS counts exactly what the
-    legacy global did: one compile per bucket on a cold sweep, zero on a
-    cache hit — traced or not."""
+def test_jit_calls_alias_counts_cold_vs_cached(tmp_path, cold_programs):
+    """The registry-backed engine.JIT_CALLS counts the programs the engine
+    builds: one per bucket on a cold sweep, zero on an artifact-cache hit,
+    and zero again when a later sweep of the same shapes finds every
+    bucket program kept — traced or not."""
     spec = tiny_spec("tel_parity", ms=(1, 2, 4, 8))   # 2 buckets @ ratio 2
     cd = str(tmp_path / "cache")
 
@@ -416,13 +417,19 @@ def test_jit_calls_alias_counts_cold_vs_cached(tmp_path):
     assert engine.JIT_CALLS - j0 == 0
     assert runner.SWEEP_COMPUTES - s0 == 0
 
-    # tracing ON changes neither count (one wrapper per bucket, counted
-    # at jit-wrap time)
+    # a fresh artifact cache recomputes the sweep, traced: both bucket
+    # programs are kept, so it builds none and hits twice
     trace.start()
-    j0 = engine.JIT_CALLS
+    j0, h0 = engine.JIT_CALLS, _program_hits()
     runner.run_sweep(spec, cache_dir=str(tmp_path / "cache2"))
     trace.stop()
-    assert engine.JIT_CALLS - j0 == 2
+    assert engine.JIT_CALLS - j0 == 0
+    assert _program_hits() - h0 == 2
+
+
+def _program_hits():
+    return metrics.REGISTRY.counter("repro_engine_program_cache_total",
+                                    labels={"outcome": "hit"}).value
 
 
 def test_module_getattr_raises_for_unknown():
@@ -456,7 +463,7 @@ def test_artifact_bytes_identical_with_tracing(tmp_path):
     assert raw_on == raw_off
 
 
-def test_trace_covers_sweep_with_bucket_split(tmp_path):
+def test_trace_covers_sweep_with_bucket_split(tmp_path, cold_programs):
     """Acceptance: a traced sweep's root span attributes >=95% of the
     traced wall-clock; each bucket span holds the lower and compile of its
     program, and each grid holds the fetch that waits for the device."""
@@ -491,7 +498,8 @@ def test_trace_covers_sweep_with_bucket_split(tmp_path):
         assert "fetch" in children(g)
 
 
-def test_compile_phase_spans_nest_in_their_bucket_with_fun_name(tmp_path):
+def test_compile_phase_spans_nest_in_their_bucket_with_fun_name(
+        tmp_path, cold_programs):
     """JAX's compile events become spans one level below the bucket that
     triggered them, named for the bucket's program."""
     spec = tiny_spec("tel_phases", ms=(1, 2, 4))
@@ -512,13 +520,14 @@ def test_compile_phase_spans_nest_in_their_bucket_with_fun_name(tmp_path):
             assert lo <= e["ts"] and e["ts"] + e["dur"] <= hi
         assert mine["compile"]["args"]["depth"] == b["args"]["depth"] + 1
         assert mine["lower"]["args"]["depth"] == b["args"]["depth"] + 1
-    # const_bytes: the train and test arrays, plus draws that widen with
-    # the bucket's m_pad, counted from shapes
+    # arg_bytes: the train and test arrays, plus draws that widen with
+    # the bucket's m_pad, counted from shapes; both programs were built
+    assert [b["args"]["cached"] for b in buckets] == [False, False]
     ds = spec.datasets["d0"]
     tr, te = spec_mod.split_dataset(ds, spec_mod.build_dataset(ds),
                                     spec.split_seed)
     data = sum(a.nbytes for a in (tr.X, tr.y, te.X, te.y))
-    small, wide = (b["args"]["const_bytes"] for b in buckets)
+    small, wide = (b["args"]["arg_bytes"] for b in buckets)
     assert data < small < wide
     # a jaxpr trace nested in another sits one level below it
     top = next(e for e in evs if e["name"] == "jaxpr_trace"
@@ -531,7 +540,8 @@ def test_compile_phase_spans_nest_in_their_bucket_with_fun_name(tmp_path):
 
 
 @pytest.mark.parametrize("traced", [False, True], ids=["off", "on"])
-def test_jax_compile_counter_matches_backend_events(traced, tmp_path):
+def test_jax_compile_counter_matches_backend_events(traced, tmp_path,
+                                                   cold_programs):
     """repro_jax_compiles_total rises by exactly the number of JAX
     backend-compile events, whether or not a tracer runs; the phase
     seconds rise with them."""
@@ -593,7 +603,7 @@ def test_tracing_off_makes_no_span_or_annotation(tmp_path, monkeypatch):
     assert other["t0_ns"] <= other["anchor_ns"]
 
 
-def test_bucket_programs_are_named(tmp_path):
+def test_bucket_programs_are_named(tmp_path, cold_programs):
     """The engine jits each bucket as bucket_<algorithm>_m<m_pad>, so the
     profiler's modules line reads jit_bucket_<algorithm>_m<m_pad>."""
     names = []
@@ -616,9 +626,10 @@ def test_bucket_programs_are_named(tmp_path):
                        "jit(bucket_minibatch_m4)"]
 
 
-def test_sequential_path_identical_traced(tmp_path):
+def test_sequential_path_identical_traced(tmp_path, cold_programs):
     """use_vmap=False (repeated jit calls) takes the plain-span path —
-    same losses traced or not, and no per-call recompiles."""
+    same losses traced or not, and no per-call recompiles: one program
+    serves every m, and the traced rerun reuses it."""
     ds = synth.make_higgs_like(KEY, n=96, d=8)
     tr, te = ds.split(key=KEY)
     kw = dict(iters=40, eval_every=20, use_vmap=False)
@@ -626,10 +637,11 @@ def test_sequential_path_identical_traced(tmp_path):
     r_off = engine.sweep("minibatch", tr, te, [1, 2, 4], **kw)
     assert engine.JIT_CALLS - j0 == 1      # one jit serves every m
     trace.start()
-    j0 = engine.JIT_CALLS
+    j0, h0 = engine.JIT_CALLS, _program_hits()
     r_on = engine.sweep("minibatch", tr, te, [1, 2, 4], **kw)
     tracer = trace.stop()
-    assert engine.JIT_CALLS - j0 == 1
+    assert engine.JIT_CALLS - j0 == 0
+    assert _program_hits() - h0 == 1
     np.testing.assert_array_equal(np.asarray(r_off["losses"]),
                                   np.asarray(r_on["losses"]))
     assert sum(e["name"] == "grid_member" for e in tracer.events) == 3
