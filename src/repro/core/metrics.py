@@ -31,6 +31,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import runtime
+from repro.telemetry import metrics as telemetry_metrics
+from repro.telemetry import trace
+
+#: bytes a host-side character pulls from the device, by call site
+_HOST_FETCH_BYTES = telemetry_metrics.counter(
+    "repro_host_fetch_bytes_total", labels={"site": "diversity"},
+    help="bytes pulled from the device to the host, by call site")
 
 
 def feature_mean(X):
@@ -56,10 +63,15 @@ def density(X, tol=0.0):
 
 
 def diversity(X, *, decimals=6):
-    """Number of distinct sample kinds (exact row dedup)."""
-    Xr = np.asarray(jax.device_get(X))
-    Xr = np.round(Xr, decimals)
-    return int(np.unique(Xr, axis=0).shape[0])
+    """Number of distinct sample kinds (exact row dedup).  The rows are
+    pulled to the host for `np.unique`: a ``diversity`` span with their
+    ``bytes``, counted in ``repro_host_fetch_bytes_total{site}``."""
+    nbytes = int(np.prod(X.shape)) * np.dtype(X.dtype).itemsize
+    _HOST_FETCH_BYTES.inc(nbytes)
+    with trace.span("diversity", rows=int(X.shape[0]), bytes=nbytes):
+        Xr = np.asarray(jax.device_get(X))
+        Xr = np.round(Xr, decimals)
+        return int(np.unique(Xr, axis=0).shape[0])
 
 
 def diversity_ratio(X, **kw):

@@ -40,7 +40,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.distributed.mesh import DeviceMesh
-from repro.telemetry import instrument, trace
+from repro.telemetry import instrument, metrics, trace
+
+#: bytes copied to the mesh's devices by replicating each bucket's
+#: arguments: their bytes times the device count
+_REPLICATED_BYTES = metrics.counter(
+    "repro_mesh_replicated_bytes_total",
+    help="bytes replicated to every device of a mesh (bytes x devices)")
 
 
 def pad_to_multiple(n: int, k: int) -> int:
@@ -112,12 +118,19 @@ def run_grid_sharded(make_sim_elem: Callable, ms: Sequence[int],
                         elements=len(m_idx)):
             m_arr = jax.device_put(m_idx, sharded)
             s_arr = jax.device_put(s_idx, sharded)
+        arg_bytes = instrument.nbytes(args)
+        _REPLICATED_BYTES.inc(arg_bytes * dmesh.n_devices)
+        with trace.span("replicate", bytes=arg_bytes,
+                        devices=dmesh.n_devices):
             args = jax.device_put(args, dmesh.replicated())
+            if trace.enabled():
+                # traced, the span waits for the copies to land so that it
+                # times the transfer; untraced, dispatch is not held up
+                args = jax.block_until_ready(args)
         out = instrument.dispatch(
             jfn, m_arr, s_arr, *args, span_name="mesh_bucket",
             algorithm=algorithm, m_pad=m_pad, devices=dmesh.n_devices,
-            elements=len(m_idx), cached=cached,
-            arg_bytes=instrument.nbytes(args))
+            elements=len(m_idx), cached=cached, arg_bytes=arg_bytes)
         with trace.span("gather", elements=n_real):
             out = np.asarray(jax.device_get(out))[:n_real]
         out = out.reshape(len(pos), n_seeds, -1)

@@ -454,7 +454,10 @@ def _compute_sweep_inner(spec: SweepSpec, fp: str, cache_dir: str, *,
                 X = datasets[job.dataset].X
                 if job.predict_rows > 0:
                     X = X[:job.predict_rows]
-                jr["predicted"] = _predict(alg_cls.predictor, X, job.kwargs)
+                with trace.span("predict", predictor=alg_cls.predictor,
+                                rows=int(X.shape[0]), d=int(X.shape[1])):
+                    jr["predicted"] = _predict(alg_cls.predictor, X,
+                                               job.kwargs)
 
         result["jobs"][job.key] = jr
         _flight("job_stored", sweep=spec.name, job=job.key, status=status,

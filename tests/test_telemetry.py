@@ -4,6 +4,9 @@ Prometheus-text conformance, and the observational contract (artifact
 bytes identical with telemetry on or off)."""
 
 import json
+import subprocess
+import sys
+import textwrap
 import threading
 
 import numpy as np
@@ -11,6 +14,7 @@ import pytest
 
 import jax
 
+from repro.core import metrics as MX
 from repro.data import synth
 from repro.experiments import cache as artifact_cache
 from repro.experiments import engine
@@ -645,6 +649,131 @@ def test_sequential_path_identical_traced(tmp_path, cold_programs):
     np.testing.assert_array_equal(np.asarray(r_off["losses"]),
                                   np.asarray(r_on["losses"]))
     assert sum(e["name"] == "grid_member" for e in tracer.events) == 3
+
+
+# ---------------------------------------------------------------------------
+# readouts and the mesh's data placement (Table II at real-sim's width)
+# ---------------------------------------------------------------------------
+
+def _inside(child, parent):
+    return (parent["ts"] <= child["ts"] and child["ts"] + child["dur"]
+            <= parent["ts"] + parent["dur"]
+            and child["args"]["depth"] > parent["args"]["depth"])
+
+
+def test_predict_span_per_predicted_job(tmp_path):
+    """Each job that asks for a prediction has one predict span inside
+    its readout, naming the predictor and the rows and width it read."""
+    spec = tiny_spec(
+        "tel_predict", ms=(1, 2),
+        datasets={"d0": DatasetSpec("higgs_like", {"n": 96, "d": 8}),
+                  "s0": DatasetSpec("realsim_like",
+                                    {"n": 80, "d": 40, "density": 0.1})},
+        jobs=(JobSpec("minibatch", "d0", predict=True),
+              JobSpec("ecd_psgd", "d0"),
+              JobSpec("dadm", "s0", predict=True, predict_rows=50)))
+    tracer = trace.start()
+    runner.run_sweep(spec, cache_dir=str(tmp_path / "c"))
+    trace.stop()
+    evs = tracer.events
+    predicts = [e for e in evs if e["name"] == "predict"]
+    assert [(p["args"]["predictor"], p["args"]["rows"], p["args"]["d"])
+            for p in predicts] == [("sync", 96, 8), ("dadm", 50, 40)]
+    readouts = [e for e in evs if e["name"] == "readout"]
+    for p, key in zip(predicts, ("minibatch/d0", "dadm/s0")):
+        assert any(r["args"]["key"] == key and _inside(p, r)
+                   for r in readouts)
+    # DADM's predictor counts diversity over its rows, inside its span
+    assert any(e["name"] == "diversity" and e["args"]["rows"] == 50
+               and _inside(e, predicts[1]) for e in evs)
+
+
+def test_diversity_span_and_counter_count_the_host_pull():
+    fetched = metrics.counter("repro_host_fetch_bytes_total",
+                              labels={"site": "diversity"})
+    X = synth.make_realsim_like(KEY, n=64, d=300, density=0.05).X
+    before = fetched.value
+    tracer = trace.start()
+    kinds = MX.diversity(X)
+    trace.stop()
+    (span,) = [e for e in tracer.events if e["name"] == "diversity"]
+    assert span["args"]["rows"] == 64
+    assert span["args"]["bytes"] == 64 * 300 * 4
+    assert fetched.value - before == 64 * 300 * 4
+    assert kinds == MX.diversity(np.asarray(X))
+
+
+MESH_PLACEMENT = """
+    import tempfile
+    from repro.experiments import cache as artifact_cache
+    from repro.experiments import runner, spec as spec_mod
+    from repro.experiments.spec import (DatasetSpec, EpsilonSpec, JobSpec,
+                                        SweepSpec)
+    from repro.telemetry import instrument, metrics, trace
+
+    spec = SweepSpec(
+        name="tel_mesh", ms=(1, 2, 4), iters=40, eval_every=20, n_seeds=2,
+        datasets={"d0": DatasetSpec("higgs_like", {"n": 96, "d": 8}),
+                  "s0": DatasetSpec("realsim_like",
+                                    {"n": 80, "d": 40, "density": 0.1})},
+        jobs=(JobSpec("minibatch", "d0", predict=True),
+              JobSpec("dadm", "s0", predict=True, predict_rows=50)),
+        epsilon=EpsilonSpec(probe_m=2)).validate()
+    fp = spec_mod.fingerprint(spec)
+    replicated = metrics.counter("repro_mesh_replicated_bytes_total")
+    with tempfile.TemporaryDirectory() as off, \\
+            tempfile.TemporaryDirectory() as on:
+        runner.run_sweep(spec, cache_dir=off, mesh=4)
+        before = replicated.value
+        tracer = trace.start()
+        res = runner.run_sweep(spec, cache_dir=on, mesh=4)
+        trace.stop()
+        assert res["execution"]["sharded"]
+        raw = [open(artifact_cache.artifact_path(d, spec.name, fp),
+                    "rb").read() for d in (off, on)]
+    assert raw[0] == raw[1]
+    evs = tracer.events
+    reps = [e for e in evs if e["name"] == "replicate"]
+    buckets = [e for e in evs if e["name"] == "mesh_bucket"]
+    # one replicate per bucket, just before its dispatch: minibatch's
+    # buckets [1, 2] and [4], DADM's one flat bucket
+    assert [b["args"]["m_pad"] for b in buckets] == [2, 4, 4]
+    assert len(reps) == len(buckets)
+    data = {}
+    for name, ds in spec.datasets.items():
+        tr, te = spec_mod.split_dataset(ds, spec_mod.build_dataset(ds),
+                                        spec.split_seed)
+        data[name] = instrument.nbytes((tr.X, tr.y, te.X, te.y))
+    # each bucket's arguments: its dataset, and draws of int32 indices
+    # (seeds x iters x m_pad; DADM's x its local batch of 8)
+    want = [data["d0"] + 2 * 40 * 2 * 4, data["d0"] + 2 * 40 * 4 * 4,
+            data["s0"] + 2 * 40 * 4 * 8 * 4]
+    for r, b, w in zip(reps, buckets, want):
+        assert r["args"]["devices"] == 4
+        assert r["args"]["bytes"] == b["args"]["arg_bytes"] == w
+        assert r["ts"] + r["dur"] <= b["ts"]
+    assert replicated.value - before == 4 * sum(want)
+    print("MESH_PLACEMENT_OK")
+"""
+
+
+@pytest.mark.slow
+def test_replicate_span_and_counter_on_four_devices():
+    """On 4 virtual devices, a sharded sweep replicates each bucket's
+    arguments under one replicate span (bytes of one copy, from their
+    shapes), the counter rises by bytes x 4, and the artifact's bytes are
+    the same traced and untraced."""
+    script = textwrap.dedent("""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        import sys; sys.path.insert(0, "src")
+        import jax
+        assert len(jax.devices()) == 4
+    """) + textwrap.dedent(MESH_PLACEMENT)
+    r = subprocess.run([sys.executable, "-c", script], cwd=".",
+                       capture_output=True, text=True, timeout=420)
+    assert r.returncode == 0, (r.stdout[-2000:], r.stderr[-2000:])
+    assert "MESH_PLACEMENT_OK" in r.stdout
 
 
 # ---------------------------------------------------------------------------
