@@ -57,8 +57,9 @@ def masked_dataset_characters(Xs, row_mask, col_mask) -> Dict:
     ``(n_slots, R)`` and ``col_mask`` ``(n_slots, D)`` are 1.0 on real
     rows/columns.  Returns ``(n_slots,)`` arrays for every maskable
     character (variance, sparsity, density, the Thm-2 Hogwild! params);
-    `diversity` needs an exact row dedup and stays a host-side per-slot
-    pass (see `ScalabilityAdvisor.dataset_characters_batch`).  All-padding
+    `diversity` needs an exact row dedup and is counted per slot by
+    `core.metrics.diversity` (see
+    `ScalabilityAdvisor.dataset_characters_batch`).  All-padding
     slots (inactive batch slots) produce zeros, never NaN."""
     rm = row_mask[:, :, None]                        # (s, R, 1)
     cm = col_mask[:, None, :]                        # (s, 1, D)
@@ -265,7 +266,8 @@ class ScalabilityAdvisor:
         Pads every dataset to the group's (rows, features) envelope and a
         slot count of ``max(n_slots, len(Xs))``, runs
         :func:`masked_dataset_characters` once, then finishes the one
-        non-vmappable index (exact-dedup `diversity`) per slot on host.
+        non-vmappable index (exact-dedup `diversity`) per slot: on the
+        device for a device array, with `np.unique` for a NumPy one.
         Invalid entries come back as None (callers pair them with
         :meth:`invalid_report`); the returned dicts carry exactly the
         characters the `repro.analysis.fit` ``*_from_characters``
@@ -291,8 +293,8 @@ class ScalabilityAdvisor:
         for s, i in enumerate(valid):
             ch = {k: (int(v[s]) if k in ("n", "d") else float(v[s]))
                   for k, v in batched.items()}
-            # exact row dedup stays on host: np.unique has no masked
-            # fixed-shape analogue worth jitting
+            # exact row dedup of the unpadded rows: a masked envelope
+            # would pad in rows of zeros that count as a kind
             ch["diversity"] = MX.diversity(Xs[i])
             ch["diversity_ratio"] = ch["diversity"] / max(ch["n"], 1)
             out[i] = ch

@@ -29,6 +29,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro import runtime
 from repro.telemetry import metrics as telemetry_metrics
@@ -38,6 +39,22 @@ from repro.telemetry import trace
 _HOST_FETCH_BYTES = telemetry_metrics.counter(
     "repro_host_fetch_bytes_total", labels={"site": "diversity"},
     help="bytes pulled from the device to the host, by call site")
+
+#: diversity counts by the path that answered: the device's hash sort,
+#: the host's np.unique, or the host after the device could not vouch
+_DIVERSITY_COUNTS = {
+    path: telemetry_metrics.counter(
+        "repro_diversity_counts_total", labels={"path": path},
+        help="diversity counts, by the path that answered")
+    for path in ("device", "host", "fallback")}
+
+#: rows are compared as ``np.round(X, 6)`` leaves them
+_DECIMALS = 6
+#: below this ``|rint(x * 1e6)|`` (|x| < 16) distinct values stay distinct
+#: after the host's division by 1e6: float32's spacing there is under 1e-6
+_EXACT_BELOW = 16 * 10 ** _DECIMALS
+#: odd multipliers of the two row hashes' per-column weights
+_HASH_MULTIPLIERS = (0x9E3779B1, 0x85EBCA77)
 
 
 def feature_mean(X):
@@ -62,20 +79,79 @@ def density(X, tol=0.0):
     return 1.0 - sparsity(X, tol)
 
 
-def diversity(X, *, decimals=6):
-    """Number of distinct sample kinds (exact row dedup).  The rows are
-    pulled to the host for `np.unique`: a ``diversity`` span with their
-    ``bytes``, counted in ``repro_host_fetch_bytes_total{site}``."""
-    nbytes = int(np.prod(X.shape)) * np.dtype(X.dtype).itemsize
-    _HOST_FETCH_BYTES.inc(nbytes)
-    with trace.span("diversity", rows=int(X.shape[0]), bytes=nbytes):
-        Xr = np.asarray(jax.device_get(X))
-        Xr = np.round(Xr, decimals)
+def _hash_weights(d):
+    """(2, d) fixed odd uint32 column weights, one row per hash."""
+    col = lax.iota(jnp.uint32, d)
+    return jnp.stack([(2 * col + 1) * jnp.uint32(c)
+                      for c in _HASH_MULTIPLIERS])
+
+
+def _mix(u):
+    # a bijective 32-bit finalizer, so a hash is no linear form of the bits
+    u = (u ^ (u >> 16)) * jnp.uint32(0x7FEB352D)
+    u = (u ^ (u >> 15)) * jnp.uint32(0x846CA68B)
+    return u ^ (u >> 16)
+
+
+@jax.jit
+def _row_kinds(X):
+    """Distinct rows of ``np.round(X, 6)`` by a hash sort on the device:
+    ``(kinds, collided)``, two scalars.
+
+    Each value is rounded to ``k = rint(x * 1e6)``, signed zeros made
+    one, and each row hashed twice as a wrapping weighted sum of its
+    mixed bits.  After one sort by the two hashes, equal rows are
+    neighbours; each neighbour pair is compared exactly, column by
+    column.  ``kinds`` is n less the neighbour pairs with equal hashes.
+    It is exact unless ``collided``: two different rows share both
+    hashes, a value is NaN, or ``|k|`` reaches `_EXACT_BELOW`, past which
+    the host's division can merge neighbouring k.  Below it
+    ``round(a) == round(b)`` exactly when ``k(a) == k(b)``."""
+    n, d = X.shape
+    k = jnp.round(X * float(10 ** _DECIMALS))
+    k = jnp.where(k == 0, 0.0, k)                    # -0.0 and 0.0 alike
+    inexact = ~jnp.all(jnp.abs(k) < _EXACT_BELOW)    # NaN compares false
+    w = _hash_weights(d)
+    m = _mix(lax.bitcast_convert_type(k, jnp.uint32))
+    h1 = jnp.sum(m * w[0], axis=1, dtype=jnp.uint32)
+    h2 = jnp.sum(m * w[1], axis=1, dtype=jnp.uint32)
+    h1, h2, order = lax.sort((h1, h2, lax.iota(jnp.int32, n)), num_keys=2)
+    same = (h1[1:] == h1[:-1]) & (h2[1:] == h2[:-1])
+    ks = k[order]
+    differ = jnp.any(ks[1:] != ks[:-1], axis=1)
+    return n - jnp.sum(same), jnp.any(same & differ) | inexact
+
+
+def diversity(X):
+    """Number of distinct sample kinds: the rows of ``np.round(X, 6)``,
+    counted exactly.
+
+    A float32 device array is counted where it lives (`_row_kinds`): two
+    scalars come back.  A NumPy array, or a device count that cannot
+    vouch for itself, goes through `np.unique` on the host.  A
+    ``diversity`` span (args rows, d, path, bytes pulled), the path in
+    ``repro_diversity_counts_total{path}`` and the pulled bytes in
+    ``repro_host_fetch_bytes_total{site}``."""
+    n, d = X.shape
+    with trace.span("diversity", rows=int(n), d=int(d)) as span:
+        path = "host"
+        if isinstance(X, jax.Array) and X.dtype == jnp.float32:
+            kinds, collided = jax.device_get(_row_kinds(X))
+            if not collided:
+                _DIVERSITY_COUNTS["device"].inc()
+                span.set(path="device", bytes=0)
+                return int(kinds)
+            path = "fallback"
+        nbytes = int(n) * int(d) * np.dtype(X.dtype).itemsize
+        _DIVERSITY_COUNTS[path].inc()
+        _HOST_FETCH_BYTES.inc(nbytes)
+        span.set(path=path, bytes=nbytes)
+        Xr = np.round(np.asarray(jax.device_get(X)), _DECIMALS)
         return int(np.unique(Xr, axis=0).shape[0])
 
 
-def diversity_ratio(X, **kw):
-    return diversity(X, **kw) / X.shape[0]
+def diversity_ratio(X):
+    return diversity(X) / X.shape[0]
 
 
 # ---------------------------------------------------------------------------
@@ -251,13 +327,14 @@ def hogwild_params(X, tol=0.0):
 def summarize(X, *, tau_max=8, batch_size=8):
     """All paper indices in one report."""
     hw = hogwild_params(X)
+    kinds = diversity(X)
     return {
         "n": int(X.shape[0]), "d": int(X.shape[1]),
         "mean_feature_variance": mean_feature_variance(X),
         "sparsity": sparsity(X),
         "density": density(X),
-        "diversity": diversity(X),
-        "diversity_ratio": diversity_ratio(X),
+        "diversity": kinds,
+        "diversity_ratio": kinds / X.shape[0],
         "csim_async": ls_async(X, tau_max),
         "csim_sync": ls_sync(X, batch_size),
         **hw,

@@ -17,8 +17,9 @@ group-envelope masked batch — same kernel, per-group shapes) and are
 counted in ``stats()["fallback"]``.
 
 The one §IV character that can't be masked-batched is ``diversity``
-(exact row dedup — `np.unique` has no fixed-shape analogue); it is
-finished host-side per probe, exactly as the scalar path does.
+(exact row dedup); it is finished per probe by `core.metrics.diversity`,
+exactly as the scalar path does.  A probe's rows are host NumPy here, so
+the count runs `np.unique` on the host, with nothing to pull.
 """
 
 from __future__ import annotations
@@ -83,8 +84,8 @@ class ProbeBatcher:
 
     @staticmethod
     def _finish(ch: Dict, X) -> Dict:
-        """Scalar-ize a slot's character slice and add the host-side
-        exact-dedup diversity indices."""
+        """Scalar-ize a slot's character slice and add the exact-dedup
+        diversity indices of the probe's (host) rows."""
         out = {k: (int(ch[k]) if k in ("n", "d") else float(ch[k]))
                for k in CHARACTER_KEYS}
         out["diversity"] = MX.diversity(X)

@@ -688,19 +688,39 @@ def test_predict_span_per_predicted_job(tmp_path):
                and _inside(e, predicts[1]) for e in evs)
 
 
-def test_diversity_span_and_counter_count_the_host_pull():
+@pytest.mark.parametrize("path", ["device", "host", "fallback"])
+def test_diversity_span_and_counter_count_the_host_pull(path, monkeypatch):
+    """A device array is counted on the device and pulls nothing; a NumPy
+    array, or a device count that falls back (here every row hashes
+    alike), pulls its n*d*4 bytes.  The span names the path and the
+    bytes, and each count lands in its path's counter."""
     fetched = metrics.counter("repro_host_fetch_bytes_total",
                               labels={"site": "diversity"})
+    counted = metrics.counter("repro_diversity_counts_total",
+                              labels={"path": path})
     X = synth.make_realsim_like(KEY, n=64, d=300, density=0.05).X
-    before = fetched.value
-    tracer = trace.start()
-    kinds = MX.diversity(X)
-    trace.stop()
+    pulled = 0 if path == "device" else 64 * 300 * 4
+    if path == "host":
+        X = np.asarray(X)
+    if path == "fallback":
+        monkeypatch.setattr(MX, "_hash_weights",
+                            lambda d: jax.numpy.zeros((2, d), np.uint32))
+    MX._row_kinds.clear_cache()
+    before, n0 = fetched.value, counted.value
+    try:
+        tracer = trace.start()
+        kinds = MX.diversity(X)
+        trace.stop()
+    finally:
+        MX._row_kinds.clear_cache()
     (span,) = [e for e in tracer.events if e["name"] == "diversity"]
-    assert span["args"]["rows"] == 64
-    assert span["args"]["bytes"] == 64 * 300 * 4
-    assert fetched.value - before == 64 * 300 * 4
-    assert kinds == MX.diversity(np.asarray(X))
+    assert (span["args"]["rows"], span["args"]["d"]) == (64, 300)
+    assert span["args"]["path"] == path
+    assert span["args"]["bytes"] == pulled
+    assert fetched.value - before == pulled
+    assert counted.value - n0 == 1
+    assert kinds == int(np.unique(np.round(np.asarray(X), 6),
+                                  axis=0).shape[0])
 
 
 MESH_PLACEMENT = """
