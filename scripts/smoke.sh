@@ -1,8 +1,8 @@
 #!/usr/bin/env bash
 # Smoke check: tier-1 tests + one engine sweep + the README quickstart
 # commands as written.  ~10-15 min cold on CPU (sweeps are cached, so
-# re-runs are much faster).  SMOKE_FULL=1 additionally runs the whole
-# benchmark harness instead of a single representative entry.
+# re-runs are much faster).  SMOKE_FULL=1 runs the whole tier-1 suite
+# instead of the quick loop.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -12,7 +12,7 @@ export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
 # 8 host devices via XLA_FLAGS; sweeps then take the sharded engine path)
 python -c "from repro.distributed import get_mesh; print(get_mesh().describe())"
 
-echo "== [1/5] test suite (quick loop: -m 'not slow') =="
+echo "== [1/4] test suite (quick loop: -m 'not slow') =="
 # The full tier-1 suite (ROADMAP.md) is `python -m pytest -x -q` with no
 # marker filter; the smoke loop skips @pytest.mark.slow sweep/subprocess
 # tests to stay under ~2 minutes on this CPU container.  SMOKE_FULL=1
@@ -23,20 +23,13 @@ else
     python -m pytest -x -q -m "not slow"
 fi
 
-echo "== [2/5] sweep engine: registered specs =="
+echo "== [2/4] sweep engine: registered specs =="
 python -m repro.experiments.run --list
 
-echo "== [3/5] sweep engine: Table II (upper_bound) quick =="
+echo "== [3/4] sweep engine: Table II (upper_bound) quick =="
 python -m repro.experiments.run --spec upper_bound --quick
 
-echo "== [4/5] benchmark harness =="
-if [ "${SMOKE_FULL:-0}" = "1" ]; then
-    python -m benchmarks.run
-else
-    python -m benchmarks.run --only paper_diversity
-fi
-
-echo "== [5/5] end-to-end paper study (quick) =="
+echo "== [4/4] end-to-end paper study (quick) =="
 python examples/paper_scalability_study.py
 
 echo "smoke OK"

@@ -165,9 +165,10 @@ def run_hogwild(train, test, *, m=4, iters=4000, gamma=0.1, lam=LAMBDA,
                 eval_every=100, key=None):
     """Returns dict with the convergence curve (server-iteration indexed).
 
-    Thin single-m wrapper over :func:`masked_sim` (padded exactly to m);
-    sweeps over many m should go through `engine.sweep_hogwild`, which
-    vmaps the same recurrence over the whole grid in one compile.
+    Thin single-m wrapper over :func:`masked_sim` (padded exactly to m),
+    kept as the oracle the engine's grid is tested against; sweeps over
+    many m go through `engine.sweep("hogwild", ...)`, which vmaps the
+    same recurrence over the whole grid in one compile.
     """
     key = key if key is not None else jax.random.PRNGKey(0)
     x, losses = _run(train.X, train.y, test.X, test.y, key, m, gamma, lam,

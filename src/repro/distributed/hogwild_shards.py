@@ -44,8 +44,7 @@ from repro.distributed import mesh as mesh_mod
 from repro.resilience import faults
 from repro.telemetry import instrument, metrics, recorder, trace
 
-#: compile counter for the sharded racing mode — `scripts/bench_engine.py
-#: dist_worker` snapshots it around the race timing (the engine's own
+#: compile counter for the sharded racing mode (the engine's own
 #: `JIT_CALLS` only counts grid-path compiles).  Registry-backed (PR 9);
 #: the module-level ``JIT_CALLS`` read stays source-compatible via
 #: ``__getattr__`` below.

@@ -8,8 +8,7 @@ registered `Algorithm` on any registered `Problem` over the whole worker
 grid as bucketed vmapped simulations (`engine.sweep` is the generic entry
 point), `runner.run_sweep` orchestrates a spec end to end with
 content-hashed artifact caching, and ``python -m repro.experiments.run``
-is the CLI that reproduces any figure from a spec name.  The legacy
-`benchmarks/paper_*.py` scripts are thin adapters over this package.
+is the CLI that reproduces any figure from a spec name.
 Specs with ``n_seeds > 1`` replicate every curve over a vmapped seed
 batch; `repro.analysis` consumes the replicate blocks (bootstrap CIs,
 scaling-law fits, ``python -m repro.analysis.report``).

@@ -1,8 +1,9 @@
 """Generic batched m-sweep engine: one vmapped path over the worker axis,
 dispatching through the `Algorithm` x `Problem` registries.
 
-The legacy benchmarks re-ran each algorithm once per worker count m in a
-Python loop — S separate traces, S compilations, S dispatch chains.
+The per-m `run_*` functions of `repro.core.algorithms` run each algorithm
+once per worker count m — S separate traces, S compilations, S dispatch
+chains over a grid of S.
 ENGINE_VERSION 2 re-derived each of the paper's four algorithms as a
 *masked, padded* simulation over a fixed worker axis of size ``m_pad`` in
 which the actual worker count m is ordinary traced data — but as four
@@ -53,10 +54,9 @@ vmap.  ``bucketed=False`` recovers the flat grid everywhere.
 
 ``use_vmap=False`` runs the *same* masked kernel (padded to m_top) once
 per m in a Python loop — the sequential reference path the equivalence
-tests compare against.  The per-algorithm ``sweep_*`` wrappers keep the
-ENGINE_VERSION-2 signatures; for Hogwild! the sequential path still loops
-the legacy per-m `run_hogwild`, so the vmapped grid is checked against the
-original staleness recurrence, not against another padded kernel.
+tests compare against, for every algorithm alike.  The per-m `run_*`
+functions stay as the tests' oracles (Hogwild!'s is the original
+staleness recurrence), outside the engine.
 
 **Seed axis** (ENGINE_VERSION 4): ``n_seeds > 1`` replicates every job
 over independent draw sequences — `Algorithm.make_draws` is called once
@@ -96,8 +96,6 @@ import jax.numpy as jnp
 
 from repro.core import problems as problems_mod
 from repro.core.algorithms import base as alg_base
-from repro.core.algorithms import run_hogwild
-from repro.core.algorithms.lr import LAMBDA
 from repro.data import synth
 from repro.distributed import mesh as dist_mesh
 from repro.distributed import partition as dist_partition
@@ -121,8 +119,8 @@ _PROGRAMS_LOCK = threading.Lock()
 #: each one is traced and compiled exactly once, so this is the engine's
 #: compile count.  Registry-backed (PR 9): increments are locked so the
 #: multi-threaded service counts exactly; the module-level ``JIT_CALLS``
-#: read (`scripts/bench_engine.py` snapshots, tests) stays source-
-#: compatible via ``__getattr__`` below.
+#: read (the benchmark, tests) stays source-compatible via ``__getattr__``
+#: below.
 _JIT_CALLS = metrics.counter(
     "repro_engine_jit_compiles_total",
     help="jax.jit wrappers dispatched by the engine (one XLA compile each)")
@@ -155,16 +153,6 @@ def __getattr__(name):
 def _jit(fn):
     _JIT_CALLS.inc()
     return jax.jit(fn)
-
-
-def _note_pad_waste(assignments) -> None:
-    """Record the grid's pad waste from ``(m, m_pad)`` member pairs."""
-    total = sum(pad for _, pad in assignments)
-    if total:
-        waste = 1.0 - sum(m for m, _ in assignments) / total
-        _PAD_WASTE.set(waste)
-        recorder.publish("grid", members=len(assignments),
-                         pad_waste=round(waste, 4))
 
 
 def _losses_dict(algorithm: str, ms, losses, iters: int, eval_every: int,
@@ -217,6 +205,27 @@ def _buckets(ms: Sequence[int],
     if cur:
         out.append((tuple(cur), ms[cur[-1]]))
     return out
+
+
+def _plan(ms: Sequence[int], bucketed: bool
+          ) -> List[Tuple[Tuple[int, ...], int]]:
+    """The grid's buckets — `_buckets` when ``bucketed``, else one flat
+    bucket at ``max(ms)`` — with the grid's pad waste recorded."""
+    buckets = (_buckets(ms) if bucketed
+               else [(tuple(range(len(ms))), max(ms))])
+    total = sum(m_pad * len(pos) for pos, m_pad in buckets)
+    if total:
+        waste = 1.0 - sum(ms) / total
+        _PAD_WASTE.set(waste)
+        recorder.publish("grid", members=len(ms), pad_waste=round(waste, 4))
+    return buckets
+
+
+def runs_sharded(dmesh: Optional["dist_mesh.DeviceMesh"],
+                 use_vmap: bool) -> bool:
+    """Whether `sweep` shards its grid over the resolved mesh ``dmesh``:
+    only the vmapped path, and only over more than one device."""
+    return dmesh is not None and dmesh.n_devices > 1 and use_vmap
 
 
 def _simulation(alg, prob, m_pad: int, eval_every: int, n_evals: int):
@@ -339,17 +348,14 @@ def _run_grid(bucket, ms, use_vmap: bool, bucketed: bool, algorithm: str):
     — the arrays it takes, whose bytes the ``bucket`` span reports as
     ``arg_bytes``, and whether the program was already built.
     """
-    m_top = max(ms)
+    buckets = _plan(ms, bucketed and use_vmap)
     if not use_vmap:
-        _note_pad_waste([(m, m_top) for m in ms])
+        [(_, m_top)] = buckets
         jsim, args, _ = bucket(m_top, False)   # one program serves every m
         return jnp.stack([
             instrument.dispatch(jsim, m, *args, span_name="grid_member",
                                 m=int(m), m_pad=m_top)
             for m in jnp.asarray(ms, jnp.int32)])
-    buckets = (_buckets(ms) if bucketed
-               else [(tuple(range(len(ms))), m_top)])
-    _note_pad_waste([(ms[i], m_pad) for pos, m_pad in buckets for i in pos])
     rows = [None] * len(ms)
     for pos, m_pad in buckets:
         program, args, cached = bucket(m_pad, True)
@@ -467,13 +473,9 @@ def sweep(algorithm: Union[str, alg_base.Algorithm], train, test,
     dmesh = dist_mesh.resolve(mesh)
     with trace.span("grid", algorithm=alg.name, problem=prob.name,
                     members=len(ms), n_seeds=n_seeds):
-        if dmesh is not None and dmesh.n_devices > 1 and use_vmap:
-            buckets = (_buckets(ms) if bucketed
-                       else [(tuple(range(len(ms))), m_top)])
-            _note_pad_waste([(ms[i], m_pad)
-                             for pos, m_pad in buckets for i in pos])
+        if runs_sharded(dmesh, use_vmap):
             losses = dist_partition.run_grid_sharded(
-                make_sim_elem, ms, n_seeds, dmesh, buckets,
+                make_sim_elem, ms, n_seeds, dmesh, _plan(ms, bucketed),
                 program=lambda m_pad, args, build: _program(
                     signature(("mesh", dmesh.devices), m_pad, args), build),
                 algorithm=alg.name)
@@ -482,94 +484,3 @@ def sweep(algorithm: Union[str, alg_base.Algorithm], train, test,
         return _losses_dict(alg.name, ms, losses, iters, eval_every,
                             problem=prob.name, n_seeds=n_seeds)
 
-
-def run_algorithm_sweep(algorithm: str, train, test, ms, *, iters,
-                        eval_every, use_vmap=True, bucketed=None,
-                        n_seeds=1, mesh=None, **kwargs) -> Dict:
-    """Dispatch one (algorithm, problem, dataset) job over the worker grid.
-
-    Every registered algorithm routes through the generic :func:`sweep`;
-    the four paper algorithms go via their ``sweep_*`` compatibility
-    wrappers (which only add the legacy Hogwild! sequential reference
-    path).  ``bucketed=None`` keeps each algorithm's declared default;
-    ``mesh`` is the execution-only device mesh (see :func:`sweep`).
-    """
-    fn = SWEEPERS.get(algorithm)
-    if fn is None:
-        return sweep(algorithm, train, test, ms, iters=iters,
-                     eval_every=eval_every, use_vmap=use_vmap,
-                     bucketed=bucketed, n_seeds=n_seeds, mesh=mesh,
-                     **kwargs)
-    if bucketed is not None:
-        kwargs["bucketed"] = bucketed
-    return fn(train, test, list(ms), iters=iters, eval_every=eval_every,
-              use_vmap=use_vmap, n_seeds=n_seeds, mesh=mesh, **kwargs)
-
-
-# ---------------------------------------------------------------------------
-# ENGINE_VERSION-2 compatibility wrappers (same signatures and defaults)
-# ---------------------------------------------------------------------------
-
-def sweep_minibatch(train, test, ms: Sequence[int], *, iters: int,
-                    eval_every: int, gamma=0.1, lam=LAMBDA, key=None,
-                    use_vmap=True, bucketed=True, n_seeds=1,
-                    problem="logistic", mesh=None) -> Dict:
-    return sweep("minibatch", train, test, ms, iters=iters,
-                 eval_every=eval_every, problem=problem, lam=lam, key=key,
-                 use_vmap=use_vmap, bucketed=bucketed, n_seeds=n_seeds,
-                 mesh=mesh, gamma=gamma)
-
-
-def sweep_ecd_psgd(train, test, ms: Sequence[int], *, iters: int,
-                   eval_every: int, gamma=0.1, lam=LAMBDA, compress_bits=8,
-                   key=None, use_vmap=True, bucketed=True, n_seeds=1,
-                   problem="logistic", mesh=None) -> Dict:
-    return sweep("ecd_psgd", train, test, ms, iters=iters,
-                 eval_every=eval_every, problem=problem, lam=lam, key=key,
-                 use_vmap=use_vmap, bucketed=bucketed, n_seeds=n_seeds,
-                 mesh=mesh, gamma=gamma, compress_bits=compress_bits)
-
-
-def sweep_dadm(train, test, ms: Sequence[int], *, iters: int, eval_every: int,
-               local_batch=8, lam=LAMBDA, key=None, use_vmap=True,
-               bucketed=False, n_seeds=1, problem="logistic",
-               mesh=None) -> Dict:
-    return sweep("dadm", train, test, ms, iters=iters,
-                 eval_every=eval_every, problem=problem, lam=lam, key=key,
-                 use_vmap=use_vmap, bucketed=bucketed, n_seeds=n_seeds,
-                 mesh=mesh, local_batch=local_batch)
-
-
-def sweep_hogwild(train, test, ms: Sequence[int], *, iters: int,
-                  eval_every: int, gamma=0.1, lam=LAMBDA, key=None,
-                  use_vmap=True, bucketed=True, n_seeds=1,
-                  problem="logistic", mesh=None, fault=None) -> Dict:
-    key = key if key is not None else jax.random.PRNGKey(0)
-    if (fault is None and not use_vmap and problem == "logistic"
-            and n_seeds == 1):
-        # Legacy per-m reference path (re-jits per m): the vmapped grid is
-        # equivalence-tested against this, i.e. against the original
-        # recurrence rather than against another padded kernel.
-        _JIT_CALLS.inc(len(ms))
-        curves = []
-        for m in ms:
-            with trace.span("grid_member", m=int(m), legacy=True):
-                curves.append(run_hogwild(
-                    train, test, m=int(m), iters=iters, gamma=gamma,
-                    lam=lam, eval_every=eval_every, key=key)["losses"])
-        return _losses_dict("hogwild", ms,
-                            jnp.stack([jnp.asarray(c) for c in curves]),
-                            iters, eval_every)
-    del bucketed   # force_flat: work is O(iters * d) regardless of m_pad
-    return sweep("hogwild", train, test, ms, iters=iters,
-                 eval_every=eval_every, problem=problem, lam=lam, key=key,
-                 use_vmap=use_vmap, n_seeds=n_seeds, mesh=mesh, gamma=gamma,
-                 fault=fault)
-
-
-SWEEPERS = {
-    "minibatch": sweep_minibatch,
-    "ecd_psgd": sweep_ecd_psgd,
-    "dadm": sweep_dadm,
-    "hogwild": sweep_hogwild,
-}

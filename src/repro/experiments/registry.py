@@ -1,9 +1,8 @@
 """Named sweep specs — one per paper figure/table (mirrors configs/registry).
 
 Each builder resolves a fully-concrete :class:`SweepSpec` (quick mode folds
-the CI-friendly iteration/size constants in, exactly as the legacy
-`benchmarks/paper_*.py` scripts did), so a spec name + ``quick`` flag is a
-complete, hashable description of a paper experiment:
+the CI-friendly iteration/size constants in), so a spec name + ``quick``
+flag is a complete, hashable description of a paper experiment:
 
   ``variance_sparsity``   Figs 3-5   dense-vs-sparse on minibatch/ECD/Hogwild!
   ``diversity``           Fig 6      duplication variants on DADM/minibatch

@@ -1,13 +1,13 @@
 """Sweep runner: SweepSpec -> datasets -> generic engine -> scalability.
 
-`run_sweep` is the one entry point every benchmark, example, and the CLI
-share.  For each job it
+`run_sweep` is the one entry point the benchmark, the examples, and the
+CLI share.  For each job it
 
   1. materializes the job's dataset (`spec.build_dataset`) and splits it
      70/20 per the spec's shuffle policy,
-  2. runs the worker-count grid through `engine.run_algorithm_sweep`,
-     which dispatches through the Algorithm x Problem registries (any
-     registered pair runs with zero edits here),
+  2. runs the worker-count grid through `engine.sweep`, which dispatches
+     through the Algorithm x Problem registries (any registered pair runs
+     with zero edits here),
   3. if the spec declares an epsilon readout, derives epsilon from the
      probe-m curve, converts curves to per-worker costs (§V.A.1; whether
      costs divide by m is the Algorithm class's `asynchronous` flag), and
@@ -187,7 +187,7 @@ def _run_job_with_retries(spec: SweepSpec, job, tr, te, dmesh, use_vmap: bool,
             if retry_backoff_s > 0:
                 time.sleep(retry_backoff_s * (2 ** (attempt - 1)))
         try:
-            jr = engine.run_algorithm_sweep(
+            jr = engine.sweep(
                 job.algorithm, tr, te, spec.ms, iters=spec.iters,
                 eval_every=spec.eval_every, use_vmap=use_vmap,
                 problem=job.problem, n_seeds=spec.n_seeds, mesh=dmesh,
@@ -353,7 +353,7 @@ def _compute_sweep_inner(spec: SweepSpec, fp: str, cache_dir: str, *,
     dmesh = dist_mesh.resolve(mesh if mesh is not None else spec.devices)
     execution = {
         "devices": dmesh.n_devices if dmesh is not None else 1,
-        "sharded": dmesh is not None and dmesh.n_devices > 1 and use_vmap,
+        "sharded": engine.runs_sharded(dmesh, use_vmap),
         "backend": runtime.default_platform(),
     }
 

@@ -30,8 +30,7 @@ transport, see ROADMAP).
 
 Metrics are **always on** (unlike spans): an increment is a lock +
 integer add, a few of which happen per *sweep* — never per iteration —
-so the registry costs nothing measurable on the hot path (bounded in
-`scripts/bench_engine.py`'s telemetry section).
+so the registry costs nothing measurable on the hot path.
 
 The process-default registry is :data:`REGISTRY`; the module-level
 :func:`counter` / :func:`gauge` / :func:`histogram` helpers target it.

@@ -151,12 +151,9 @@ def test_ecd_psgd_divergence_envelope():
 
     def modes(iters):
         kw = dict(iters=iters, eval_every=20, key=key)
-        b = engine.run_algorithm_sweep("ecd_psgd", tr, te, ms,
-                                       bucketed=True, **kw)
-        f = engine.run_algorithm_sweep("ecd_psgd", tr, te, ms,
-                                       bucketed=False, **kw)
-        s = engine.run_algorithm_sweep("ecd_psgd", tr, te, ms,
-                                       use_vmap=False, **kw)
+        b = engine.sweep("ecd_psgd", tr, te, ms, bucketed=True, **kw)
+        f = engine.sweep("ecd_psgd", tr, te, ms, bucketed=False, **kw)
+        s = engine.sweep("ecd_psgd", tr, te, ms, use_vmap=False, **kw)
         return (np.asarray(b["losses"]), np.asarray(f["losses"]),
                 np.asarray(s["losses"]))
 

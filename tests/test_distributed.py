@@ -184,9 +184,8 @@ def test_engine_single_device_mesh_is_bitexact():
     tr, te = ds.split(key=jax.random.PRNGKey(0))
     kw = dict(iters=60, eval_every=20)
     for algo in ("minibatch", "hogwild"):
-        r0 = engine.run_algorithm_sweep(algo, tr, te, [1, 2, 4], **kw)
-        r1 = engine.run_algorithm_sweep(algo, tr, te, [1, 2, 4], mesh=1,
-                                        **kw)
+        r0 = engine.sweep(algo, tr, te, [1, 2, 4], **kw)
+        r1 = engine.sweep(algo, tr, te, [1, 2, 4], mesh=1, **kw)
         assert np.array_equal(np.asarray(r0["losses"]),
                               np.asarray(r1["losses"]))
 
@@ -220,9 +219,9 @@ MESH_INVARIANCE = """
     for algo, n_seeds, iters in (("minibatch", 3, 400), ("hogwild", 3, 400),
                                  ("dadm", 1, 400), ("ecd_psgd", 2, 60)):
         kw = dict(iters=iters, eval_every=iters // 4, n_seeds=n_seeds)
-        r1 = engine.run_algorithm_sweep(algo, tr, te, ms, **kw)
+        r1 = engine.sweep(algo, tr, te, ms, **kw)
         j0 = engine.JIT_CALLS
-        r8 = engine.run_algorithm_sweep(algo, tr, te, ms, mesh=8, **kw)
+        r8 = engine.sweep(algo, tr, te, ms, mesh=8, **kw)
         compiles = engine.JIT_CALLS - j0
         a = np.asarray(r1.get("losses_seeds", r1["losses"]))
         b = np.asarray(r8.get("losses_seeds", r8["losses"]))

@@ -66,16 +66,19 @@ def test_spec_validation_rejects_bad_specs():
 # engine: the vmapped grid is the sequential loop
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("sweeper", [engine.sweep_minibatch,
-                                     engine.sweep_ecd_psgd,
-                                     engine.sweep_dadm,
-                                     engine.sweep_hogwild])
-def test_vmapped_equals_sequential(sweeper):
+def _paper(*algorithms):
+    """Paper algorithms as `engine.sweep` names, case ids ``sweep_<name>``."""
+    return [pytest.param(a, id=f"sweep_{a}") for a in algorithms]
+
+
+@pytest.mark.parametrize(
+    "algorithm", _paper("minibatch", "ecd_psgd", "dadm", "hogwild"))
+def test_vmapped_equals_sequential(algorithm):
     ds = synth.make_higgs_like(KEY, n=160, d=10)
     tr, te = ds.split(key=KEY)
     kw = dict(iters=60, eval_every=20)
-    v = sweeper(tr, te, [1, 2, 4], use_vmap=True, **kw)
-    s = sweeper(tr, te, [1, 2, 4], use_vmap=False, **kw)
+    v = engine.sweep(algorithm, tr, te, [1, 2, 4], use_vmap=True, **kw)
+    s = engine.sweep(algorithm, tr, te, [1, 2, 4], use_vmap=False, **kw)
     assert v["ms"] == s["ms"] == [1, 2, 4]
     np.testing.assert_allclose(v["losses"], s["losses"],
                                rtol=2e-4, atol=2e-5)
@@ -90,8 +93,8 @@ def test_hogwild_sweep_matches_single_runs():
     ds = synth.make_higgs_like(KEY, n=160, d=10)
     tr, te = ds.split(key=KEY)
     ms = [1, 2, 4, 8]
-    sw = engine.sweep_hogwild(tr, te, ms, iters=80, eval_every=20,
-                              use_vmap=True)
+    sw = engine.sweep("hogwild", tr, te, ms, iters=80, eval_every=20,
+                      use_vmap=True)
     for m, curve in curves_by_m(sw).items():
         r = run_hogwild(tr, te, m=m, iters=80, eval_every=20)
         np.testing.assert_allclose(curve, r["losses"], rtol=1e-5)
@@ -111,10 +114,9 @@ def test_buckets_partition_properties():
             assert max(members) <= engine.MAX_PAD_RATIO * min(members)
 
 
-@pytest.mark.parametrize("sweeper", [engine.sweep_minibatch,
-                                     engine.sweep_ecd_psgd,
-                                     engine.sweep_dadm])
-def test_bucketed_equals_flat(sweeper):
+@pytest.mark.parametrize("algorithm",
+                         _paper("minibatch", "ecd_psgd", "dadm"))
+def test_bucketed_equals_flat(algorithm):
     """Bucketed padding must not change numerics: draws are made at the
     global m_top and sliced per bucket, so member m's computation is
     identical whichever bucket it lands in."""
@@ -122,25 +124,29 @@ def test_bucketed_equals_flat(sweeper):
     tr, te = ds.split(key=KEY)
     kw = dict(iters=60, eval_every=20)
     ms = [1, 2, 4, 8]                 # two buckets under MAX_PAD_RATIO=2
-    b = sweeper(tr, te, ms, use_vmap=True, bucketed=True, **kw)
-    f = sweeper(tr, te, ms, use_vmap=True, bucketed=False, **kw)
+    b = engine.sweep(algorithm, tr, te, ms, use_vmap=True, bucketed=True,
+                     **kw)
+    f = engine.sweep(algorithm, tr, te, ms, use_vmap=True, bucketed=False,
+                     **kw)
     np.testing.assert_allclose(b["losses"], f["losses"],
                                rtol=2e-4, atol=2e-5)
 
 
-@pytest.mark.parametrize("sweeper,legacy,kwname", [
-    (engine.sweep_minibatch, run_minibatch, "batch_size"),
-    (engine.sweep_ecd_psgd, run_ecd_psgd, "m"),
-    (engine.sweep_dadm, run_dadm, "m"),
-])
-def test_engine_matches_legacy_at_full_m(sweeper, legacy, kwname):
+@pytest.mark.parametrize("algorithm,legacy,kwname", [
+    pytest.param(a, legacy, kwname,
+                 id=f"sweep_{a}-{legacy.__name__}-{kwname}")
+    for a, legacy, kwname in (("minibatch", run_minibatch, "batch_size"),
+                              ("ecd_psgd", run_ecd_psgd, "m"),
+                              ("dadm", run_dadm, "m"))])
+def test_engine_matches_legacy_at_full_m(algorithm, legacy, kwname):
     """At m == m_max the padded grid uses the same index draws as the legacy
     per-m runner (same key, same shapes, all-ones mask), so the sweep's last
     row must reproduce the original algorithm's curve almost exactly."""
     ds = synth.make_higgs_like(KEY, n=160, d=10)
     tr, te = ds.split(key=KEY)
     m_max = 4
-    sw = sweeper(tr, te, [1, 2, m_max], iters=60, eval_every=20)
+    sw = engine.sweep(algorithm, tr, te, [1, 2, m_max], iters=60,
+                      eval_every=20)
     r = legacy(tr, te, iters=60, eval_every=20, **{kwname: m_max})
     np.testing.assert_allclose(curves_by_m(sw)[m_max], r["losses"],
                                rtol=2e-4, atol=2e-5)
@@ -150,8 +156,7 @@ def test_engine_rejects_unknown_algorithm():
     ds = synth.make_higgs_like(KEY, n=64, d=4)
     tr, te = ds.split(key=KEY)
     with pytest.raises(KeyError):
-        engine.run_algorithm_sweep("sgd9000", tr, te, [1],
-                                   iters=20, eval_every=20)
+        engine.sweep("sgd9000", tr, te, [1], iters=20, eval_every=20)
 
 
 # ---------------------------------------------------------------------------
@@ -166,9 +171,8 @@ def test_seeded_seed0_matches_single_seed_grid(algorithm):
     ds = synth.make_higgs_like(KEY, n=160, d=10)
     tr, te = ds.split(key=KEY)
     kw = dict(iters=60, eval_every=20)
-    single = engine.run_algorithm_sweep(algorithm, tr, te, [1, 2, 4], **kw)
-    seeded = engine.run_algorithm_sweep(algorithm, tr, te, [1, 2, 4],
-                                        n_seeds=3, **kw)
+    single = engine.sweep(algorithm, tr, te, [1, 2, 4], **kw)
+    seeded = engine.sweep(algorithm, tr, te, [1, 2, 4], n_seeds=3, **kw)
     assert single["n_seeds"] == 1 and "losses_seeds" not in single
     assert seeded["n_seeds"] == 3
     np.testing.assert_allclose(seeded["losses"], single["losses"],

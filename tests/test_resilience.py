@@ -109,10 +109,10 @@ def test_fault_stream_is_seeded_and_shaped():
                                      ("local_sgd", {"gamma": 0.1})])
 def test_zero_rate_bit_exact_engine(algo, kw):
     tr, te = _data()
-    clean = engine.run_algorithm_sweep(algo, tr, te, [1, 2, 4], iters=60,
-                                       eval_every=20, **kw)
-    zero = engine.run_algorithm_sweep(algo, tr, te, [1, 2, 4], iters=60,
-                                      eval_every=20, fault={}, **kw)
+    clean = engine.sweep(algo, tr, te, [1, 2, 4], iters=60, eval_every=20,
+                         **kw)
+    zero = engine.sweep(algo, tr, te, [1, 2, 4], iters=60, eval_every=20,
+                        fault={}, **kw)
     np.testing.assert_array_equal(np.asarray(clean["losses"]),
                                   np.asarray(zero["losses"]))
 
@@ -141,7 +141,7 @@ def test_zero_rate_bit_exact_race():
                                      ("local_sgd", {"gamma": 0.1})])
 def test_faulted_runs_reproducible_and_distinct(algo, kw):
     tr, te = _data()
-    run = lambda f: engine.run_algorithm_sweep(       # noqa: E731
+    run = lambda f: engine.sweep(                     # noqa: E731
         algo, tr, te, [1, 2, 4], iters=60, eval_every=20, fault=f, **kw)
     a, b = run(FAULT), run(FAULT)
     np.testing.assert_array_equal(np.asarray(a["losses"]),
@@ -163,7 +163,7 @@ def test_fault_schedule_shared_across_seed_replicates():
     single trace) — while a different *fault* seed moves the curves by
     orders of magnitude more."""
     tr, te = _data()
-    run = lambda **kw: engine.run_algorithm_sweep(     # noqa: E731
+    run = lambda **kw: engine.sweep(                  # noqa: E731
         "hogwild", tr, te, [1, 2], iters=60, eval_every=20, gamma=0.05, **kw)
     one = run(fault=FAULT)
     many = run(fault=FAULT, n_seeds=3)
@@ -276,7 +276,7 @@ def test_journal_resume_is_byte_identical(tmp_path, monkeypatch):
     uninterrupted = runner.run_sweep(spec, cache_dir=a)
     golden = open(uninterrupted["cache"]["path"], "rb").read()
 
-    real = engine.run_algorithm_sweep
+    real = engine.sweep
     calls = []
 
     def crashing(*args, **kwargs):
@@ -285,7 +285,7 @@ def test_journal_resume_is_byte_identical(tmp_path, monkeypatch):
             raise KeyboardInterrupt("simulated SIGKILL")
         return real(*args, **kwargs)
 
-    monkeypatch.setattr(engine, "run_algorithm_sweep", crashing)
+    monkeypatch.setattr(engine, "sweep", crashing)
     with pytest.raises(KeyboardInterrupt):
         runner.run_sweep(spec, cache_dir=b)
     jpath = journal.journal_path(b, spec.name, fingerprint(spec))
@@ -298,7 +298,7 @@ def test_journal_resume_is_byte_identical(tmp_path, monkeypatch):
         counting.append(args)
         return real(*args, **kwargs)
 
-    monkeypatch.setattr(engine, "run_algorithm_sweep", counted)
+    monkeypatch.setattr(engine, "sweep", counted)
     resumed = runner.run_sweep(spec, cache_dir=b)
     assert len(counting) == 1                     # only job 2 recomputed
     assert open(resumed["cache"]["path"], "rb").read() == golden
@@ -319,7 +319,7 @@ def test_journal_disabled_or_uncached_writes_nothing(tmp_path):
 
 def test_transient_failure_retries_to_ok(tmp_path, monkeypatch):
     spec = _tiny_spec(name="res_retry")
-    real = engine.run_algorithm_sweep
+    real = engine.sweep
     calls = []
 
     def flaky(*args, **kwargs):
@@ -328,7 +328,7 @@ def test_transient_failure_retries_to_ok(tmp_path, monkeypatch):
             raise RuntimeError("transient device loss")
         return real(*args, **kwargs)
 
-    monkeypatch.setattr(engine, "run_algorithm_sweep", flaky)
+    monkeypatch.setattr(engine, "sweep", flaky)
     res = runner.run_sweep(spec, cache_dir=str(tmp_path),
                            retry_backoff_s=0.0)
     jr = res["jobs"]["minibatch/d0"]
@@ -343,7 +343,7 @@ def test_permanent_failure_becomes_structured_stub(tmp_path, monkeypatch):
     def broken(*args, **kwargs):
         raise RuntimeError("device pool gone")
 
-    monkeypatch.setattr(engine, "run_algorithm_sweep", broken)
+    monkeypatch.setattr(engine, "sweep", broken)
     with pytest.warns(RuntimeWarning, match="failed after 2 attempt"):
         res = runner.run_sweep(spec, cache_dir=str(tmp_path),
                                retry_backoff_s=0.0)
@@ -364,7 +364,7 @@ def test_cli_exits_nonzero_on_failed_job(monkeypatch, capsys):
     def broken(*args, **kwargs):
         raise RuntimeError("device pool gone")
 
-    monkeypatch.setattr(engine, "run_algorithm_sweep", broken)
+    monkeypatch.setattr(engine, "sweep", broken)
     with pytest.warns(RuntimeWarning, match="failed after"):
         rc = cli.main(["--spec", "variance_sparsity", "--quick",
                        "--iters", "40", "--n", "120", "--no-cache"])
@@ -495,14 +495,14 @@ KILL_RESUME_BODY = """
         epsilon=EpsilonSpec(probe_m=1, frac=0.7)).validate()
 
     cache_dir, mode = sys.argv[1], sys.argv[2]
-    real = engine.run_algorithm_sweep
+    real = engine.sweep
     calls = [0]
     def wrapper(*a, **k):
         calls[0] += 1
         if mode == "kill" and calls[0] == 2:
             os.kill(os.getpid(), signal.SIGKILL)   # job 1 journaled, die
         return real(*a, **k)
-    engine.run_algorithm_sweep = wrapper
+    engine.sweep = wrapper
     res = runner.run_sweep(spec, cache_dir=cache_dir)
     print("CALLS", calls[0])
     print("PATH", res["cache"]["path"])
@@ -555,7 +555,7 @@ FAULTED_PARITY_BODY = """
     # racing dropped-delta vs the sequential fault oracle at m == D,
     # sync_every=1 (threefry streams are flat-identical at equal counts)
     m, iters, ev = 8, 1600, 200
-    oracle = engine.run_algorithm_sweep(
+    oracle = engine.sweep(
         "hogwild", tr, te, [m], iters=iters, eval_every=ev,
         gamma=0.05, fault=FAULT)
     race = run_hogwild_sharded(tr, te, m=m, iters=iters, gamma=0.05,
@@ -570,12 +570,10 @@ FAULTED_PARITY_BODY = """
     ms = [1, 2, 4, 8]
     for algo, kw in (("hogwild", {"gamma": 0.05}),
                      ("local_sgd", {"gamma": 0.1})):
-        r1 = engine.run_algorithm_sweep(algo, tr, te, ms, iters=400,
-                                        eval_every=100, n_seeds=2,
-                                        fault=FAULT, **kw)
-        r8 = engine.run_algorithm_sweep(algo, tr, te, ms, iters=400,
-                                        eval_every=100, n_seeds=2,
-                                        fault=FAULT, mesh=8, **kw)
+        r1 = engine.sweep(algo, tr, te, ms, iters=400, eval_every=100,
+                          n_seeds=2, fault=FAULT, **kw)
+        r8 = engine.sweep(algo, tr, te, ms, iters=400, eval_every=100,
+                          n_seeds=2, fault=FAULT, mesh=8, **kw)
         d = float(np.abs(np.asarray(r1["losses_seeds"])
                          - np.asarray(r8["losses_seeds"])).max())
         print("invariance", algo, d)
