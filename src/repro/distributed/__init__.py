@@ -12,9 +12,10 @@ suite under ``XLA_FLAGS=--xla_force_host_platform_device_count=8``).
                     single-device fallback that is bit-exact with the
                     unsharded engine path.  Also hosts the model stack's
                     named-mesh builders (absorbed from `repro.launch.mesh`).
-  `partition`       the grid partitioner: flattens each bucket's
-                    (members x seeds) cells into one padded element axis,
-                    lays it over the mesh, one jit per bucket.
+  `partition`       the grid partitioner: splits each bucket's seeds over
+                    the mesh when they divide evenly, else flattens its
+                    (members x seeds) cells into one padded element axis
+                    laid over the mesh; one jit per bucket.
   `hogwild_shards`  TRUE multi-device Hogwild! — worker shards racing on a
                     donated shared parameter under ``shard_map``; the
                     engine's sequential staleness recurrence remains the
@@ -33,8 +34,8 @@ from repro.distributed.mesh import (SHARD_AXIS, DeviceMesh, MeshLike,
                                     from_devices, get_mesh,
                                     make_debug_mesh, make_production_mesh,
                                     resolve)
-from repro.distributed.partition import (element_plan, pad_to_multiple,
-                                         run_grid_sharded)
+from repro.distributed.partition import (element_plan, layout,
+                                         pad_to_multiple, run_grid_sharded)
 from repro.distributed.rules import (FSDP_AXES, act_constraint, batch_specs,
                                      data_axes, decode_act_constraint,
                                      decode_state_specs, head_constraint,
@@ -45,7 +46,7 @@ from repro.distributed.rules import (FSDP_AXES, act_constraint, batch_specs,
 __all__ = [
     "SHARD_AXIS", "DeviceMesh", "MeshLike", "from_devices", "get_mesh",
     "resolve", "make_debug_mesh", "make_production_mesh",
-    "element_plan", "pad_to_multiple", "run_grid_sharded",
+    "element_plan", "layout", "pad_to_multiple", "run_grid_sharded",
     "run_hogwild_sharded", "sweep_hogwild_sharded",
     "FSDP_AXES", "act_constraint", "batch_specs", "data_axes",
     "decode_act_constraint", "decode_state_specs", "head_constraint",

@@ -37,7 +37,7 @@ SHARD_AXIS = "shard"
 
 @dataclasses.dataclass(frozen=True)
 class DeviceMesh:
-    """A 1-D mesh over the engine's batched grid-element axis.
+    """A 1-D mesh over the engine's batched seed or grid-element axis.
 
     Thin, picklable-ish wrapper around ``jax.sharding.Mesh((n,),
     ('shard',))`` carrying the derived shardings the partitioner needs.
@@ -56,7 +56,8 @@ class DeviceMesh:
         return tuple(self.mesh.devices.flat)
 
     def sharding(self) -> NamedSharding:
-        """Leading-axis sharding for a batched array of grid elements."""
+        """Leading-axis sharding: grid-element indices, or draws stacked
+        on their seed axis."""
         return NamedSharding(self.mesh, P(SHARD_AXIS))
 
     def replicated(self) -> NamedSharding:

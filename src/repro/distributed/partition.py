@@ -3,32 +3,38 @@
 The generic engine (`repro.experiments.engine`) runs each bucket of the
 worker grid as ONE vmapped simulation — a batch whose elements are
 independent ``(grid member m, seed replicate s)`` cells.  Independence is
-the whole trick: the batch axis can be laid out across devices with
-``jax.sharding`` and every element still computes exactly what it computes
-on one device, so results are **mesh-invariant** (tested at 1e-5; see
-docs/distributed.md for the contract).
+the whole trick: the batch can be laid out across devices and every
+element still computes exactly what it computes on one device, so results
+are **mesh-invariant** (tested at 1e-5; see docs/distributed.md for the
+contract).
 
 :func:`run_grid_sharded` is the distributed twin of the engine's
-``_run_grid``: for every bucket it
+``_run_grid``.  Each bucket takes one of two layouts, chosen by
+:func:`layout` from shapes alone:
 
-  1. flattens the bucket's (members x seeds) cells into one element axis
-     — so a 4-member bucket with 8 seed replicates exposes 32 units of
-     parallelism, not 4 (the seed axis shards too, per the tentpole),
-  2. pads that axis to a multiple of the device count by repeating the
-     first element (cheapest correct filler; the rows are dropped after),
-  3. lays the padded ``(m, s)`` index arrays over the mesh's ``'shard'``
-     axis with :class:`jax.sharding.NamedSharding`, replicates the other
-     arguments (dataset, draws) on every device and dispatches ONE jitted
-     vmap — computation follows the input sharding, so XLA splits the
-     batch across devices,
-  4. gathers, drops the padding rows, and scatters results back to grid
-     order.
+* **seed** (``n_seeds % n_devices == 0``): the seed axis is split over
+  the mesh's ``'shard'`` axis by one ``jax.shard_map``.  Each device runs
+  the engine's own unsharded bucket program on its ``n_seeds /
+  n_devices`` seeds — the members vmapped over the seed-vmapped
+  simulation, so every member of a seed shares that seed's draws, and
+  with them its row gathers.  The dataset is replicated, the draws are
+  split (each device holds only its seeds'), and the body holds no
+  collectives.  No padding.
+* **element** (otherwise, e.g. one seed on four devices): the bucket's
+  (members x seeds) cells are flattened into one element axis, padded to
+  a multiple of the device count by repeating the first element, and the
+  ``(m, s)`` index arrays are laid over the mesh; the dataset and draws
+  are replicated, and ONE jitted vmap over the elements follows the
+  index sharding.  Each element gathers its own draws, so members of one
+  seed share nothing; in exchange the padding wastes at most one element
+  per device, where padding the seed axis would waste whole seeds.
 
-One jit per bucket signature, exactly like the unsharded path — the
-engine keeps the program per process, so a later sweep of the same shapes
-on the same mesh compiles nothing.  The engine owns bucket policy and its
-program cache; both arrive as arguments, which keeps this module free of
-engine imports.
+Either way the results are gathered and scattered back to grid order.
+One jit per bucket signature and layout, exactly like the unsharded
+path — the engine keeps the program per process, so a later sweep of the
+same shapes on the same mesh compiles nothing.  The engine owns bucket
+policy and its program cache; both arrive as arguments, which keeps this
+module free of engine imports.
 """
 
 from __future__ import annotations
@@ -38,8 +44,9 @@ from typing import Callable, List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
-from repro.distributed.mesh import DeviceMesh
+from repro.distributed.mesh import SHARD_AXIS, DeviceMesh
 from repro.telemetry import instrument, metrics, trace
 
 #: bytes copied to the mesh's devices by replicating each bucket's
@@ -47,6 +54,20 @@ from repro.telemetry import instrument, metrics, trace
 _REPLICATED_BYTES = metrics.counter(
     "repro_mesh_replicated_bytes_total",
     help="bytes replicated to every device of a mesh (bytes x devices)")
+
+#: One increment per bucket dispatched on a mesh, by its :func:`layout`.
+_MESH_BUCKETS = {
+    name: metrics.counter(
+        "repro_mesh_buckets_total", labels={"layout": name},
+        help="buckets dispatched on a mesh, by layout: seed axis split "
+             "over the devices, or flat (m, seed) elements")
+    for name in ("seed", "element")}
+
+
+def layout(n_seeds: int, n_devices: int) -> str:
+    """``"seed"`` when the seeds divide evenly over the devices, so each
+    device runs whole seeds with no padding; else ``"element"``."""
+    return "seed" if n_seeds % n_devices == 0 else "element"
 
 
 def pad_to_multiple(n: int, k: int) -> int:
@@ -72,65 +93,99 @@ def element_plan(pos: Sequence[int], ms: Sequence[int], n_seeds: int,
             n_real)
 
 
-def _fresh_program(m_pad, args, build):
+def _fresh_program(m_pad, how, args, build):
     return jax.jit(build()), False
 
 
-def run_grid_sharded(make_sim_elem: Callable, ms: Sequence[int],
+def _seed_program(sim, dmesh: DeviceMesh):
+    """Members vmapped over the seed-vmapped ``sim``, run by every device
+    on its own seeds: ``(m_vec, data, stacked) -> (members, n_seeds,
+    n_evals)``, the seed axis split over the mesh."""
+    def body(m_vec, data, stacked):
+        def member(m):
+            # the seed axis stays even where a device holds one seed
+            return jax.vmap(lambda sub: sim(m, data, sub))(stacked)
+        return jax.vmap(member)(m_vec)
+
+    return jax.shard_map(body, mesh=dmesh.mesh,
+                         in_specs=(P(), P(), P(SHARD_AXIS)),
+                         out_specs=P(None, SHARD_AXIS), check_vma=False)
+
+
+def _element_program(sim):
+    """``sim`` over a flat (m, seed) element axis, each element taking its
+    seed's draws by the traced index: ``(data, stacked, m_idx, s_idx) ->
+    (elements, n_evals)``."""
+    def sim_elem(data, stacked, m, s):
+        return sim(m, data, jax.tree.map(lambda a: a[s], stacked))
+
+    return jax.vmap(sim_elem, in_axes=(None, None, 0, 0))
+
+
+def run_grid_sharded(make_sim: Callable, ms: Sequence[int],
                      n_seeds: int, dmesh: DeviceMesh,
                      buckets: List[Tuple[Tuple[int, ...], int]],
                      program: Callable = _fresh_program,
                      algorithm: str = "sim") -> jnp.ndarray:
     """Run the whole grid sharded over ``dmesh``; rows follow ``ms`` order.
 
-    ``make_sim_elem(m_pad)`` must return ``(sim_elem, args)``:
-    ``sim_elem(m, s, *args) -> (n_evals,)`` obeying the engine's
-    masked-simulation contract (numerics independent of ``m_pad`` for any
-    ``m <= m_pad``), and the arrays it takes besides ``(m, s)``, which are
-    replicated on every device (their bytes are the ``mesh_bucket``
-    span's ``arg_bytes``); ``buckets`` is the engine's ``[(positions,
-    m_pad), ...]`` partition (a single flat bucket for ``force_flat``
-    algorithms).  ``program(m_pad, args, build)`` returns ``(jitted,
-    cached)``: the jit of ``build()``, which the engine keeps per process
-    (and counts in ``JIT_CALLS``) and the default builds afresh.  Each
-    bucket's program is named ``bucket_<algorithm>_m<m_pad>``.
+    ``make_sim(m_pad)`` must return ``(sim, data, stacked)``: ``sim(m,
+    data, sub) -> (n_evals,)`` obeying the engine's masked-simulation
+    contract (numerics independent of ``m_pad`` for any ``m <= m_pad``),
+    the arrays every simulation shares (replicated on every device; their
+    bytes are the ``replicate`` span's ``bytes``), and the draws stacked
+    on a leading axis of ``n_seeds``, of which ``sim`` takes one seed's
+    as ``sub``.  ``buckets`` is the engine's ``[(positions, m_pad), ...]``
+    partition (a single flat bucket for ``force_flat`` algorithms).
+    ``program(m_pad, layout, args, build)`` returns ``(jitted, cached)``:
+    the jit of ``build()``, which the engine keeps per process (and counts
+    in ``JIT_CALLS``) and the default builds afresh.  Each bucket's
+    program is named ``bucket_<algorithm>_m<m_pad>``.
 
     Returns ``(S, n_evals)`` for ``n_seeds == 1``, else
     ``(S, n_seeds, n_evals)`` — the same contract as the engine's
     ``_run_grid``, so `_losses_dict` consumes either path unchanged.
     """
-    sharded = dmesh.sharding()
+    how = layout(n_seeds, dmesh.n_devices)
     rows: List = [None] * len(ms)
     for pos, m_pad in buckets:
-        m_idx, s_idx, n_real = element_plan(pos, ms, n_seeds,
-                                            dmesh.n_devices)
-        sim_elem, args = make_sim_elem(m_pad)
+        sim, data, stacked = make_sim(m_pad)
 
         def build():
-            # vmapped over the flat (m, s) element axis; every element
-            # shares the other arguments
-            return instrument.named(
-                jax.vmap(sim_elem, in_axes=(0, 0) + (None,) * len(args)),
-                f"bucket_{algorithm}_m{m_pad}")
+            fn = (_seed_program(sim, dmesh) if how == "seed"
+                  else _element_program(sim))
+            return instrument.named(fn, f"bucket_{algorithm}_m{m_pad}")
 
-        jfn, cached = program(m_pad, args, build)
+        jfn, cached = program(m_pad, how, (data, stacked), build)
+        if how == "seed":
+            # the seeds are split, so only the members and data are copied
+            split = [stacked]
+            placed = [np.asarray([ms[i] for i in pos], np.int32), data]
+            n_real = n_elems = len(pos) * n_seeds
+        else:
+            m_idx, s_idx, n_real = element_plan(pos, ms, n_seeds,
+                                                dmesh.n_devices)
+            split = [m_idx, s_idx]
+            placed = [data, stacked]
+            n_elems = len(m_idx)
         with trace.span("shard_put", devices=dmesh.n_devices,
-                        elements=len(m_idx)):
-            m_arr = jax.device_put(m_idx, sharded)
-            s_arr = jax.device_put(s_idx, sharded)
-        arg_bytes = instrument.nbytes(args)
-        _REPLICATED_BYTES.inc(arg_bytes * dmesh.n_devices)
-        with trace.span("replicate", bytes=arg_bytes,
+                        elements=n_elems):
+            split = jax.device_put(split, dmesh.sharding())
+        rep_bytes = instrument.nbytes(placed)
+        _REPLICATED_BYTES.inc(rep_bytes * dmesh.n_devices)
+        with trace.span("replicate", bytes=rep_bytes,
                         devices=dmesh.n_devices):
-            args = jax.device_put(args, dmesh.replicated())
+            placed = jax.device_put(placed, dmesh.replicated())
             if trace.enabled():
                 # traced, the span waits for the copies to land so that it
                 # times the transfer; untraced, dispatch is not held up
-                args = jax.block_until_ready(args)
+                placed = jax.block_until_ready(placed)
+        _MESH_BUCKETS[how].inc()
         out = instrument.dispatch(
-            jfn, m_arr, s_arr, *args, span_name="mesh_bucket",
+            jfn, *placed, *split, span_name="mesh_bucket",
             algorithm=algorithm, m_pad=m_pad, devices=dmesh.n_devices,
-            elements=len(m_idx), cached=cached, arg_bytes=arg_bytes)
+            layout=how, elements=n_elems, cached=cached,
+            arg_bytes=instrument.nbytes(data, stacked))
         with trace.span("gather", elements=n_real):
             out = np.asarray(jax.device_get(out))[:n_real]
         out = out.reshape(len(pos), n_seeds, -1)

@@ -34,10 +34,10 @@ arguments rather than closed over as constants.  Its closure holds only
 static values — the frozen `Algorithm` and `Problem` instances, ``m_pad``,
 ``iters``, ``eval_every``, ``n_seeds`` — so the jitted program is kept in
 a bounded, locked LRU (`PROGRAM_CACHE_SIZE`) keyed on those, the mode
-(vmapped, sequential or a mesh) and the shapes and dtypes of its
-arguments.  A hit skips trace, lowering and compile: same-shape datasets
-of one spec share a program, and later sweeps of the same shapes build
-nothing.  ``JIT_CALLS`` counts the misses;
+(vmapped, sequential, or a mesh and its layout) and the shapes and dtypes
+of its arguments.  A hit skips trace, lowering and compile: same-shape
+datasets of one spec share a program, and later sweeps of the same shapes
+build nothing.  ``JIT_CALLS`` counts the misses;
 ``repro_engine_program_cache_total{outcome}`` counts both.
 
 **Bucketed padding** (`_buckets`): a flat padded grid does S * work(m_top)
@@ -72,11 +72,13 @@ as the seed-0 rows (every legacy consumer unchanged) and add
 stats` turns into mean/CI curves and bootstrap m_max distributions.
 
 **Device-mesh sharding** (ENGINE_VERSION 5): ``mesh=`` hands each
-bucket's batched simulation to `repro.distributed.partition`, which
-flattens the (members x seeds) cells into one element axis, pads it to
-the device count, and dispatches ONE jitted vmap whose inputs are laid
-over the mesh — XLA then splits the batch across devices.  Because the
-cells are independent, results are **mesh-invariant** (1e-5 contract,
+bucket's batched simulation to `repro.distributed.partition`.  When the
+seeds divide evenly over the devices, each device runs this module's
+bucket program (members vmapped over the seed-vmapped simulation) on its
+own seeds under one ``shard_map``; otherwise the (members x seeds) cells
+are flattened into one element axis, padded to the device count, and
+ONE jitted vmap follows the element indices laid over the mesh.  Because
+the cells are independent, results are **mesh-invariant** (1e-5 contract,
 tests/test_distributed.py) and cache fingerprints exclude the mesh
 entirely.  ``mesh=None`` (every existing caller) and single-device
 meshes take the exact unsharded path below — the single-device fallback
@@ -454,17 +456,12 @@ def sweep(algorithm: Union[str, alg_base.Algorithm], train, test,
             build)
         return program, args, cached
 
-    def make_sim_elem(m_pad):
-        # distributed twin of `bucket`: one simulation per (m, seed) cell,
-        # with the seed's draws gathered by the traced index — the
-        # partitioner vmaps this over a flat element axis laid across the
-        # mesh, so the seed axis shards exactly like the grid axis
-        sim = _simulation(alg, prob, m_pad, eval_every, n_evals)
-
-        def sim_elem(m, s, data, stacked):
-            return sim(m, data, jax.tree.map(lambda a: a[s], stacked))
-
-        return sim_elem, operands(m_pad, True)
+    def make_sim(m_pad):
+        # distributed twin of `bucket`: the per-seed simulation and its
+        # operands, the draws always stacked on their seed axis, which
+        # the partitioner splits over the mesh or indexes per element
+        return (_simulation(alg, prob, m_pad, eval_every, n_evals),
+                *operands(m_pad, True))
 
     if bucketed is None:
         bucketed = alg.bucketed_default
@@ -475,9 +472,10 @@ def sweep(algorithm: Union[str, alg_base.Algorithm], train, test,
                     members=len(ms), n_seeds=n_seeds):
         if runs_sharded(dmesh, use_vmap):
             losses = dist_partition.run_grid_sharded(
-                make_sim_elem, ms, n_seeds, dmesh, _plan(ms, bucketed),
-                program=lambda m_pad, args, build: _program(
-                    signature(("mesh", dmesh.devices), m_pad, args), build),
+                make_sim, ms, n_seeds, dmesh, _plan(ms, bucketed),
+                program=lambda m_pad, layout, args, build: _program(
+                    signature(("mesh", dmesh.devices, layout), m_pad, args),
+                    build),
                 algorithm=alg.name)
         else:
             losses = _run_grid(bucket, ms, use_vmap, bucketed, alg.name)

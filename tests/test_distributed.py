@@ -16,8 +16,8 @@ import numpy as np
 import pytest
 
 from repro.data import synth
-from repro.distributed import (element_plan, get_mesh, pad_to_multiple,
-                               resolve, run_grid_sharded)
+from repro.distributed import (element_plan, get_mesh, layout,
+                               pad_to_multiple, resolve, run_grid_sharded)
 from repro.distributed import mesh as mesh_mod
 from repro.experiments import engine
 from repro.experiments.spec import (DatasetSpec, JobSpec, SweepSpec,
@@ -73,23 +73,68 @@ def test_element_plan_layout():
     assert list(m_idx) == [1, 2, 4, 1] and list(s_idx) == [0, 0, 0, 0]
 
 
+def test_layout_rule():
+    # seeds that divide evenly over the devices are split over them...
+    for n_seeds, n_devices in ((8, 4), (4, 4), (8, 8), (16, 8), (6, 2),
+                               (1, 1), (3, 1)):
+        assert layout(n_seeds, n_devices) == "seed", (n_seeds, n_devices)
+    # ...anything else flattens (m, seed) elements and pads
+    for n_seeds, n_devices in ((1, 4), (2, 4), (3, 8), (6, 4), (2, 8),
+                               (5, 4)):
+        assert layout(n_seeds, n_devices) == "element", (n_seeds, n_devices)
+
+
+@dataclasses.dataclass(frozen=True)
+class _FourWide(mesh_mod.DeviceMesh):
+    """A one-device mesh the partitioner plans for as four devices: the
+    layout rule and the element padding see four, the arrays land on the
+    one device there is."""
+
+    @property
+    def n_devices(self):
+        return 4
+
+
 def test_run_grid_sharded_matches_direct_eval():
-    """The partitioner's pad/reshape/scatter bookkeeping, on a 1-device
-    mesh with an analytic sim_elem (3 'evals' encoding m, s, m_pad)."""
+    """The partitioner's layout choice, pad/reshape/scatter bookkeeping
+    and seed axis, with an analytic sim (3 'evals' encoding m, the seed's
+    draw and m_pad) planned for four devices: 1 and 3 seeds take the
+    element plan and pad it, 4 and 8 split their seeds and pad nothing."""
+    from repro.distributed import partition
+    from repro.telemetry import trace
+
     ms = [1, 2, 3, 4, 6, 8]
-    dmesh = get_mesh(1)
+    dmesh = _FourWide(get_mesh(1).mesh)
+    counted = partition._MESH_BUCKETS
 
-    def make_sim_elem(m_pad):
-        def sim_elem(m, s):
-            return jnp.stack([m.astype(jnp.float32), s.astype(jnp.float32),
-                              jnp.float32(m_pad)])
-        return sim_elem, ()
+    for n_seeds in (1, 3, 4, 8):
+        def make_sim(m_pad):
+            def sim(m, data, sub):
+                return jnp.stack([m.astype(jnp.float32), sub,
+                                  jnp.float32(m_pad)])
+            # seed s's draw is 10 s, so a row names its seed
+            return sim, (), 10.0 * jnp.arange(n_seeds, dtype=jnp.float32)
 
-    for n_seeds in (1, 3):
+        how = layout(n_seeds, 4)
+        assert how == ("seed" if n_seeds in (4, 8) else "element")
         for buckets in (engine._buckets(ms),
                         [(tuple(range(len(ms))), max(ms))]):
-            out = np.asarray(run_grid_sharded(
-                make_sim_elem, ms, n_seeds, dmesh, buckets))
+            before = {k: c.value for k, c in counted.items()}
+            tracer = trace.start()
+            try:
+                out = np.asarray(run_grid_sharded(
+                    make_sim, ms, n_seeds, dmesh, buckets))
+            finally:
+                trace.stop()
+            assert {k: c.value - before[k] for k, c in counted.items()} \
+                == {k: len(buckets) * (k == how) for k in counted}
+            spans = [e["args"] for e in tracer.events
+                     if e["name"] == "mesh_bucket"]
+            real = [len(pos) * n_seeds for pos, _ in buckets]
+            assert [a["layout"] for a in spans] == [how] * len(buckets)
+            assert [a["elements"] for a in spans] == (
+                real if how == "seed"
+                else [pad_to_multiple(r, 4) for r in real])
             pad_of = {i: m_pad for pos, m_pad in buckets for i in pos}
             if n_seeds == 1:
                 assert out.shape == (len(ms), 3)
@@ -99,7 +144,7 @@ def test_run_grid_sharded_matches_direct_eval():
                 assert out.shape == (len(ms), n_seeds, 3)
                 for i, m in enumerate(ms):
                     for s in range(n_seeds):
-                        assert list(out[i, s]) == [m, s, pad_of[i]]
+                        assert list(out[i, s]) == [m, 10 * s, pad_of[i]]
 
 
 # ---------------------------------------------------------------------------
@@ -211,16 +256,23 @@ def _run_sub(body, timeout):
 MESH_INVARIANCE = """
     from repro.data import synth
     from repro.experiments import engine
+    from repro.telemetry import metrics
 
     ds = synth.make_higgs_like(jax.random.PRNGKey(0), n=400, d=16)
     tr, te = ds.split(key=jax.random.PRNGKey(0))
     ms = [1, 2, 4, 8]
-    # deterministic-arithmetic algorithms: sweep scale, with seed axis
+    laid = {how: metrics.counter("repro_mesh_buckets_total",
+                                 labels={"layout": how})
+            for how in ("seed", "element")}
+    # deterministic-arithmetic algorithms: sweep scale, with seed axis;
+    # 1, 2 and 3 seeds take the element plan on 8 devices, 8 seeds split
     for algo, n_seeds, iters in (("minibatch", 3, 400), ("hogwild", 3, 400),
-                                 ("dadm", 1, 400), ("ecd_psgd", 2, 60)):
+                                 ("dadm", 1, 400), ("ecd_psgd", 2, 60),
+                                 ("dadm", 8, 400), ("minibatch", 8, 400)):
         kw = dict(iters=iters, eval_every=iters // 4, n_seeds=n_seeds)
         r1 = engine.sweep(algo, tr, te, ms, **kw)
         j0 = engine.JIT_CALLS
+        l0 = {how: c.value for how, c in laid.items()}
         r8 = engine.sweep(algo, tr, te, ms, mesh=8, **kw)
         compiles = engine.JIT_CALLS - j0
         a = np.asarray(r1.get("losses_seeds", r1["losses"]))
@@ -232,7 +284,12 @@ MESH_INVARIANCE = """
             engine.alg_base.get_algorithm(algo).bucketed_default
             and not engine.alg_base.get_algorithm(algo).force_flat) else 1
         assert compiles == n_buckets, (algo, compiles, n_buckets)
-        print(algo, "invariant", d, "compiles", compiles)
+        # every bucket dispatched once, in the layout the seeds choose
+        how = "seed" if n_seeds == 8 else "element"
+        counts = {k: c.value - l0[k] for k, c in laid.items()}
+        assert counts == {k: n_buckets * (k == how) for k in laid}, (
+            algo, n_seeds, counts)
+        print(algo, n_seeds, "invariant", d, "compiles", compiles, how)
     print("MESH_INVARIANCE_OK")
 """
 
